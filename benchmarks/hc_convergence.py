@@ -13,7 +13,7 @@ Reports simulator evaluations, device dispatches and wall time for all
 three (same final answer — asserted within 2 VMs), with the wall time of
 each mode split into XLA compile vs execute+host (the ``qn.compile_ms``
 counters of ``repro.obs.compile``) — on a warm persistent compile cache
-(``REPRO_COMPILE_CACHE``) the compile share drops to ~0.
+(``repro.obs.compile``) the compile share drops to ~0.
 
 All three gaits run with ``race=False`` (the analytic-locked VM choice)
 so the comparison isolates gait economics: the point-wise walk always

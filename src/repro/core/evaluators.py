@@ -383,11 +383,8 @@ def amva_frontier(cls: ApplicationClass, vm: VMType, nu_lo: int, nu_hi: int,
     think = jnp.full((len(nus),), cls.think_ms, jnp.float32)
     h = jnp.full((len(nus),), float(cls.h_users), jnp.float32)
     if use_kernel:
-        try:
-            from repro.kernels.amva import ops as amva_ops
-            return np.asarray(amva_ops.ps_fixed_point(a_over_c, bb, think, h))
-        except Exception:
-            pass
+        from repro.kernels.amva import ops as amva_ops
+        return np.asarray(amva_ops.ps_fixed_point(a_over_c, bb, think, h))
     return np.asarray(ps_response_batch(a_over_c, bb, think, h))
 
 

@@ -58,7 +58,6 @@ from functools import partial
 from typing import Callable, Dict, Tuple
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from repro.core import shapes as _shapes
@@ -166,10 +165,10 @@ def _sharded(fn: Callable, shards: int, n_lane: int, n_shared: int,
         return got
     mesh = lanes_mesh(shards)
     inner = partial(fn, **dict(static_kw))
-    wrapped = jax.jit(shard_map(
+    wrapped = jax.jit(jax.shard_map(
         inner, mesh=mesh,
         in_specs=(_LANES,) * n_lane + (_REPL,) * n_shared,
-        out_specs=_LANES, check_rep=False))
+        out_specs=_LANES, check_vma=False))
     with _LOCK:
         got = _CALLS.setdefault(key, wrapped)
     return got

@@ -305,13 +305,15 @@ def _sim_batch_jit(n_map, n_reduce, m_avg, r_avg, think_ms, slots_cap, seed,
 # oracle above; ``impl="pallas"`` dispatches the SAME padded batch to the
 # fused Pallas event-step kernel (repro.kernels.qn_event), whose contract
 # is bit-exact parity in interpret mode (tests/test_qn_event_kernel.py).
-# The process default comes from $REPRO_QN_IMPL so racing, coordination
-# and windowed planning switch transparently; ``set_default_impl`` flips
-# it at runtime (dispatch accounting is impl-independent by construction).
+# The process default follows the platform — the compiled kernel on TPU,
+# the scan on CPU, where the kernel only runs interpreted — unless
+# $REPRO_QN_IMPL names one, so racing, coordination and windowed planning
+# switch transparently; ``set_default_impl`` flips it at runtime (dispatch
+# accounting is impl-independent by construction).
 # ---------------------------------------------------------------------------
 
 QN_IMPLS = ("jnp", "pallas")
-_DEFAULT_IMPL = os.environ.get("REPRO_QN_IMPL", "jnp")
+_DEFAULT_IMPL = os.environ.get("REPRO_QN_IMPL")     # None: by platform
 
 
 def set_default_impl(impl: str) -> None:
@@ -323,7 +325,10 @@ def set_default_impl(impl: str) -> None:
 
 
 def default_impl() -> str:
-    return _DEFAULT_IMPL
+    if _DEFAULT_IMPL is not None:
+        return _DEFAULT_IMPL
+    from repro.kernels import interpret_mode
+    return "jnp" if interpret_mode() else "pallas"
 
 
 def _batch_sim_fn(impl):
@@ -335,7 +340,7 @@ def _batch_sim_fns(impl):
     public single-device entry point (spans included), ``inner`` the bare
     jitted program ``partition.shard_call`` wraps in ``shard_map`` — the
     sharded path opens its span at the dispatch site instead."""
-    impl = _DEFAULT_IMPL if impl is None else impl
+    impl = default_impl() if impl is None else impl
     if impl == "jnp":
         return _sim_batch_jit, _sim_batch_jit
     if impl == "pallas":
@@ -402,7 +407,7 @@ def _count_dispatch(n: int = 1, *, lanes: int = None, padded_lanes: int = 0,
         # totals: sim_stats()/dispatch_count() read the bare counters and
         # stay bit-identical whether or not anyone looks at labels.
         _QN_COUNTERS["dispatches"].labels(
-            kind=kind, impl=impl if impl is not None else _DEFAULT_IMPL,
+            kind=kind, impl=impl if impl is not None else default_impl(),
         ).inc(n)
         _QN_COUNTERS["lanes"].inc(n if lanes is None else lanes)
         _QN_COUNTERS["padded_lanes"].inc(padded_lanes)

@@ -19,8 +19,7 @@ CINECA PICO 20-core node (1 container/core, faster cores).
 """
 from __future__ import annotations
 
-import json
-import os
+import functools
 from dataclasses import dataclass, replace
 from typing import Dict, List, Optional, Tuple
 
@@ -111,21 +110,12 @@ def calibrate(s: Scenario, *, tol: float = 0.02, max_iter: int = 18,
     return test
 
 
-_CACHE_PATH = os.path.join(os.path.dirname(__file__), "_calibrated.json")
-
-
-def calibrated_specs(use_cache: bool = True) -> Dict[int, WorkloadSpec]:
-    """Calibrated spec per Table-3 row index (cached to JSON)."""
-    if use_cache and os.path.exists(_CACHE_PATH):
-        raw = json.loads(open(_CACHE_PATH).read())
-        if len(raw) == len(TABLE3):
-            return {int(k): WorkloadSpec(**v) for k, v in raw.items()}
-    out = {}
-    for i, s in enumerate(TABLE3):
-        out[i] = calibrate(s)
-    with open(_CACHE_PATH, "w") as f:
-        json.dump({k: v.__dict__ for k, v in out.items()}, f, indent=1)
-    return out
+@functools.lru_cache(maxsize=None)
+def calibrated_specs() -> Dict[int, WorkloadSpec]:
+    """Calibrated spec per Table-3 row index.  Recomputed once per process
+    from the table and the seeded simulator (about 2 s for all twelve
+    rows), so the specs always match the code that made them."""
+    return {i: calibrate(s) for i, s in enumerate(TABLE3)}
 
 
 def spec_for_query_250g(query: str) -> WorkloadSpec:
@@ -142,24 +132,42 @@ def spec_for_query_250g(query: str) -> WorkloadSpec:
 def scenario_problem(query: str, users: int, deadline_ms: float,
                      vm_types: Optional[List[VMType]] = None,
                      eta: float = 0.3, profile_seed: int = 55):
-    """Single-class Problem for the cost-vs-deadline scenarios (§4.3).
+    """Single-class Problem for the cost-vs-deadline scenarios (§4.3): the
+    query's 250 GB profile planned for ``users`` concurrent users."""
+    return _class_problem(spec_for_query_250g(query), f"{query}-{users}u",
+                          users, deadline_ms, vm_types, eta, profile_seed)
 
-    Profiles + replayer lists are extracted per VM type from dedicated
-    profiling runs (the §4.1 methodology: same query, both deployments)."""
+
+def table3_problem(row: int, deadline_ms: float,
+                   vm_types: Optional[List[VMType]] = None,
+                   eta: float = 0.3, profile_seed: int = 55):
+    """Single-class Problem for Table-3 row ``row`` at its published task
+    counts, data scale and user count."""
+    s = TABLE3[row]
+    return _class_problem(calibrated_specs()[row],
+                          f"{s.query}-{s.dataset_gb}G-{s.users}u", s.users,
+                          deadline_ms, vm_types, eta, profile_seed)
+
+
+def _class_problem(spec: WorkloadSpec, name: str, users: int,
+                   deadline_ms: float, vm_types: Optional[List[VMType]],
+                   eta: float, profile_seed: int):
+    """Profiles + replayer lists are extracted per VM type from dedicated
+    profiling runs (the §4.1 methodology: same query, both deployments).
+    Returns ``(problem, samples, spec)``."""
     from repro.core.cluster_sim import profile_from_runs, replayer_lists
     from repro.core.problem import ApplicationClass, Problem
 
     vms = vm_types if vm_types is not None else VM_CATALOG
-    spec = spec_for_query_250g(query)
     profiles = {}
     samples = {}
     for vm in vms:
         prof = profile_from_runs(spec, speed=vm.speed, runs=20,
                                  slots=240, seed=profile_seed)
         profiles[vm.name] = prof
-        samples[(f"{query}-{users}u", vm.name)] = replayer_lists(
+        samples[(name, vm.name)] = replayer_lists(
             spec, speed=vm.speed, runs=20, slots=240, seed=profile_seed)
-    cls = ApplicationClass(name=f"{query}-{users}u", h_users=users,
+    cls = ApplicationClass(name=name, h_users=users,
                            think_ms=THINK_MS, deadline_ms=deadline_ms,
                            eta=eta, profiles=profiles)
     return Problem(classes=[cls], vm_types=list(vms)), samples, spec

@@ -10,9 +10,9 @@ into VPU-shaped ``(8, 128)`` f32 blocks — sublane x lane — and the grid
 walks row-blocks of the padded ``(rows, 128)`` candidate matrix.  The
 fixed-point / MVA iteration count is a *grid-resident* ``fori_loop``: each
 block loads its operands into VMEM once, iterates entirely on-chip
-(``PS_ITERS`` = 40 rounds, no HBM round trips), and stores one result
+(``PS_ITERS`` = 128 rounds, no HBM round trips), and stores one result
 tile.  Arithmetic intensity is ~4 flops x iters per 20 operand bytes
-(≈ 8 flop/byte at 40 iters) — comfortably compute-bound on TPU.
+(≈ 26 flop/byte at 128 iters) — compute-bound on TPU.
 
 Two kernels share the tiling:
 
@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-PS_ITERS = 40
+from repro.core.mva import PS_ITERS
 SUBLANE, LANE = 8, 128          # f32 VPU tile
 TILE = SUBLANE * LANE
 

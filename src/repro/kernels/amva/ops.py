@@ -1,7 +1,7 @@
-"""jit'd public wrappers for the batched-AMVA kernels (interpret on CPU,
-native Pallas on TPU).  ``ps_fixed_point`` backs ``evaluators.
-amva_frontier`` — the one-launch fast tier of the optimizer; ``mva_response``
-is the degenerate-case exact-MVA oracle at kernel speed.
+"""jit'd public wrappers for the batched-AMVA kernels (interpreted on CPU,
+compiled on TPU: ``kernels.interpret_mode()``).  ``ps_fixed_point`` backs
+``evaluators.amva_frontier`` — the one-launch fast tier of the optimizer;
+``mva_response`` is the degenerate-case exact-MVA oracle at kernel speed.
 
 Both wrappers open ``kernel:amva*`` telemetry spans around the jitted
 launch and label the region with ``jax.named_scope`` for XLA profiles.
@@ -14,12 +14,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import partition as _partition
+from repro.kernels import interpret_mode
 from repro.kernels.amva import kernel
 from repro.obs import trace as _obs_trace
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _bucket_args(n: int, shards: int, args):
@@ -40,7 +37,7 @@ def _ps_fixed_point_jit(a_over_c, b, think, h_users,
                         iters: int = kernel.PS_ITERS):
     with jax.named_scope("amva_ps_fixed_point"):
         return kernel.amva_fwd(a_over_c, b, think, h_users, iters=iters,
-                               interpret=not _on_tpu())
+                               interpret=interpret_mode())
 
 
 def ps_fixed_point(a_over_c, b, think, h_users, iters: int = kernel.PS_ITERS):
@@ -65,7 +62,7 @@ def ps_fixed_point(a_over_c, b, think, h_users, iters: int = kernel.PS_ITERS):
 def _mva_response_jit(demand, think, h_users: int):
     with jax.named_scope("amva_exact_mva"):
         return kernel.mva_fwd(demand, think, h_users=h_users,
-                              interpret=not _on_tpu())
+                              interpret=interpret_mode())
 
 
 def mva_response(demand, think, h_users: int):

@@ -1,4 +1,4 @@
-"""jit'd public wrapper: Pallas forward (interpret on CPU, native on TPU)
+"""jit'd public wrapper: Pallas forward (``kernels.interpret_mode()``)
 with the FA2 blockwise-recompute backward from jnp_impl."""
 from __future__ import annotations
 
@@ -6,11 +6,8 @@ from functools import partial
 
 import jax
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention import jnp_impl, kernel
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -18,7 +15,7 @@ def flash_attention(q, k, v, causal: bool = True, window: int = 0,
                     block_q: int = 512, block_k: int = 512):
     return kernel.flash_attention_fwd(
         q, k, v, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, interpret=not _on_tpu())
+        block_q=block_q, block_k=block_k, interpret=interpret_mode())
 
 
 def _fwd(q, k, v, causal, window, block_q, block_k):

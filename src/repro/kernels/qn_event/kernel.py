@@ -8,9 +8,25 @@ single biggest raw-speed lever in the repo (ROADMAP item 2).
 
 This kernel fuses the whole event loop for a *block of lanes* (lane =
 candidate x replication) into one Pallas program: the per-lane state
-(slot clocks, user phases, accumulators) lives in VMEM/registers across all
+(slot clocks, user phases, accumulators) lives in VMEM across all
 ``n_events`` steps — no HBM round trips between events — and every step's
 masked selection runs vectorized across the lane block.
+
+TPU layout
+----------
+Lanes sit on the 128-wide minor axis.  A lane block is ``LANE_TILE`` lanes
+(or the whole batch when it is narrower), and every per-lane state array
+is ``(rows, lanes)``: ``(max_slots, L)`` slot clocks, ``(H, L)`` user
+state, ``(1, L)`` per-lane scalars.  Every selection is a reduction over
+the row axis: a min over f32 plus an iota select, because the TPU
+compiler has no arg-reduction over integers or booleans.
+
+The draw tables are ``(n_events, lanes)``.  At the paper's largest event
+budgets (524,288 events per lane) they cannot sit whole in VMEM, so a
+second, sequential grid axis streams them through in ``EVENT_CHUNK``-event
+blocks.  The lane state is carried in VMEM scratch across those blocks:
+initialised at the first, written out after the last.  Inside a block,
+event ``j`` reads its draw row by ref indexing (``ref[pl.ds(j, 1), :]``).
 
 Bit-parity strategy
 -------------------
@@ -24,19 +40,19 @@ that tractable:
     service/think exponentials, or replay sample gathers) are precomputed
     OUTSIDE the kernel with exactly the oracle's calls (``fold_in``/
     ``exponential``/``randint`` in the same order, same fold offsets) and
-    passed in as ``(lanes, n_events)`` tables; the kernel itself is
+    passed in as ``(n_events, lanes)`` tables; the kernel itself is
     RNG-free.
   * The draw-consuming arithmetic (``now + e*mean``, ``t_slot +
     e*think``) keeps the oracle's exact op structure IN-KERNEL — XLA
     contracts ``add(x, mul(a, b))`` chains into FMAs inside loop bodies,
     so hoisting the multiply out of the loop would round differently by
-    1 ulp.  Everything else in the step is f32 adds/compares/min/argmin/
+    1 ulp.  Everything else in the step is f32 adds/compares/min/max/
     where — nothing else contractible — so the elementwise translation of
     the oracle step (scalar-per-lane -> lane-vectorized) is bitwise exact.
 
-State updates use gather-free one-hot ``where`` masks (TPU-friendly; the
-oracle's ``.at[u].set`` on a scalar lane places exactly one element, the
-one-hot mask places the same element with the same value).
+State updates use gather-free one-hot ``where`` masks (the oracle's
+``.at[u].set`` on a scalar lane places exactly one element, the one-hot
+mask places the same element with the same value).
 
 Degenerate lanes are honored exactly like the oracle: a pure-padding lane
 (``n_events_active == 0``) never steps and reports ``resp_cnt == 0``; a
@@ -49,11 +65,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # plain Python float (not a jnp constant: Pallas kernels may not capture
 # array constants); weak-typed to the oracle's exact f32 1e30 in every op
 INF = 1e30
-LANE_BLOCK = 8          # lanes per grid step (f32 sublane count on TPU)
+LANE_TILE = 128         # lanes per grid block: the TPU's minor tile
+EVENT_CHUNK = 1024      # events per block of the streamed draw tables
 
 
 # ---------------------------------------------------------------------------
@@ -105,54 +123,88 @@ def event_streams(m_avg, r_avg, think_ms, seed, n_events_active, *,
 # ---------------------------------------------------------------------------
 
 def _iota(shape):
-    return jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+
+
+def _first_index(mask):
+    """Row of the first True per lane, as f32 ``(1, L)``; ``rows`` where a
+    lane has none."""
+    rows = mask.shape[0]
+    io = _iota(mask.shape).astype(jnp.float32)
+    return jnp.min(jnp.where(mask, io, float(rows)), axis=0, keepdims=True)
+
+
+def _argmin(vals):
+    """``jnp.argmin(vals, axis=0)`` per lane — the first row holding the
+    minimum — as a min plus an iota select."""
+    low = jnp.min(vals, axis=0, keepdims=True)
+    return _first_index(vals == low).astype(jnp.int32)
 
 
 def _pick(vals, idx):
-    """``vals[l, idx[l]]`` per lane, gather-free (one-hot mask + sum).
-    Exact: one element survives, the rest contribute literal zeros."""
-    mask = _iota(vals.shape) == idx[:, None]
-    return jnp.sum(jnp.where(mask, vals, jnp.zeros_like(vals)), axis=1)
+    """``vals[idx[l], l]`` per lane, gather-free (one-hot mask + max).
+    Exact: one element survives, the rest are the dtype's lowest value."""
+    info = jnp.finfo if jnp.issubdtype(vals.dtype, jnp.floating) \
+        else jnp.iinfo
+    low = info(vals.dtype).min
+    return jnp.max(jnp.where(_iota(vals.shape) == idx, vals, low),
+                   axis=0, keepdims=True)
 
 
 def _place(vals, idx, new):
-    """``vals.at[l, idx[l]].set(new[l])`` per lane via one-hot ``where``."""
-    mask = _iota(vals.shape) == idx[:, None]
-    return jnp.where(mask, new[:, None], vals)
+    """``vals.at[idx[l], l].set(new[l])`` per lane via one-hot ``where``."""
+    return jnp.where(_iota(vals.shape) == idx, new, vals)
 
 
-def _event_kernel(nm_ref, nr_ref, cap_ref, nea_ref, ma_ref, ra_ref, tm_ref,
-                  think0_ref, stm_ref, str_ref, td_ref, sum_ref, cnt_ref, *,
-                  h_users: int, max_slots: int, n_events: int,
+def _state_layout(lanes: int, max_slots: int, h_users: int):
+    """``(shape, dtype, initial fill)`` of each carried state array, in
+    carry order; the ``None`` fill is the initial think clocks."""
+    f32, i32 = jnp.float32, jnp.int32
+    one, slots, users = (1, lanes), (max_slots, lanes), (h_users, lanes)
+    return ((one, f32, 0.0),            # now
+            (slots, f32, INF),          # slot_end
+            (slots, i32, -1),           # slot_user
+            (users, f32, None),         # think_end
+            (users, i32, 0),            # phase: 0 think, 1 map, 2 reduce
+            (users, i32, 0),            # pending
+            (users, i32, 0),            # inflight
+            (users, f32, INF),          # arrival
+            (users, f32, 0.0),          # job_start
+            (one, f32, 0.0),            # resp_sum
+            (one, f32, 0.0),            # resp_cnt
+            (one, i32, 0))              # done_jobs
+
+
+def _event_kernel(ip_ref, fp_ref, think0_ref, stm_ref, str_ref, td_ref,
+                  out_ref, *state_refs, layout, max_slots: int, chunk: int,
                   warmup_jobs: int, replay: bool):
-    L = nm_ref.shape[0]
-    nm = nm_ref[...]
-    nr = nr_ref[...]
-    cap = cap_ref[...]
-    nea = nea_ref[...]
-    ma = ma_ref[...]
-    ra = ra_ref[...]
-    tm = tm_ref[...]
-    st_m = stm_ref[...]                       # (L, E) draw tables
-    st_r = str_ref[...]
-    td = td_ref[...]
-    slot_enabled = _iota((L, max_slots)) < cap[:, None]
+    c = pl.program_id(1)                      # event block (sequential)
+    L = ip_ref.shape[1]
+    nm, nr, cap, nea = (ip_ref[k:k + 1, :] for k in range(4))
+    ma, ra, tm = (fp_ref[k:k + 1, :] for k in range(3))
+    slot_enabled = _iota((max_slots, L)) < cap
 
-    def step(i, s):
+    @pl.when(c == 0)
+    def _init():
+        for ref, (shape, dt, fill) in zip(state_refs, layout):
+            ref[...] = think0_ref[...] if fill is None \
+                else jnp.full(shape, fill, dt)
+
+    def step(j, s):
         (now, slot_end, slot_user, think_end, phase, pending, inflight,
          arrival, job_start, resp_sum, resp_cnt, done_jobs) = s
-        free_mask = (slot_user < 0) & slot_enabled
-        b_dispatch = jnp.any(free_mask, axis=1) & jnp.any(pending > 0,
-                                                          axis=1)
+        i = c * chunk + j                     # global event index
+        free_idx = _first_index((slot_user < 0) & slot_enabled)
+        any_pending = jnp.max(pending, axis=0, keepdims=True) > 0
+        b_dispatch = (free_idx < max_slots) & any_pending
 
         # ------------- dispatch one task (reduce priority, FIFO) ----------
         red_key = jnp.where((pending > 0) & (phase == 2), arrival, INF)
         map_key = jnp.where((pending > 0) & (phase == 1), arrival, INF)
-        has_red = jnp.min(red_key, axis=1) < INF
-        u = jnp.where(has_red, jnp.argmin(red_key, axis=1),
-                      jnp.argmin(map_key, axis=1)).astype(jnp.int32)
-        stm_i = jax.lax.dynamic_slice_in_dim(st_m, i, 1, 1)[:, 0]
-        str_i = jax.lax.dynamic_slice_in_dim(st_r, i, 1, 1)[:, 0]
+        has_red = jnp.min(red_key, axis=0, keepdims=True) < INF
+        u = jnp.where(has_red, _argmin(red_key), _argmin(map_key))
+        stm_i = stm_ref[pl.ds(j, 1), :]
+        str_i = str_ref[pl.ds(j, 1), :]
         if replay:
             st = jnp.where(_pick(phase, u) == 1, stm_i, str_i)
         else:
@@ -160,15 +212,17 @@ def _event_kernel(nm_ref, nr_ref, cap_ref, nea_ref, ma_ref, ra_ref, tm_ref,
             # unit draw IN the loop body — FMA-contraction parity)
             mean = jnp.where(_pick(phase, u) == 1, ma, ra)
             st = stm_i * mean
-        slot = jnp.argmax(free_mask, axis=1).astype(jnp.int32)
+        # first free slot; slot 0 when none is free (jnp.argmax's answer)
+        slot = jnp.where(free_idx < max_slots, free_idx, 0.0) \
+            .astype(jnp.int32)
         d_slot_end = _place(slot_end, slot, now + st)
         d_slot_user = _place(slot_user, slot, u)
         d_pending = _place(pending, u, _pick(pending, u) - 1)
         d_inflight = _place(inflight, u, _pick(inflight, u) + 1)
 
         # ------------- or advance time ------------------------------------
-        t_slot = jnp.min(slot_end, axis=1)
-        t_think = jnp.min(think_end, axis=1)
+        t_slot = jnp.min(slot_end, axis=0, keepdims=True)
+        t_think = jnp.min(think_end, axis=0, keepdims=True)
         b_complete = (~b_dispatch) & (t_slot <= t_think) & (t_slot < INF)
         b_think = (~b_dispatch) & (~b_complete) & (t_think < INF)
         active = i < nea                       # padded tail: no-op steps
@@ -177,7 +231,7 @@ def _event_kernel(nm_ref, nr_ref, cap_ref, nea_ref, ma_ref, ra_ref, tm_ref,
         b_think &= active
 
         # completion
-        cslot = jnp.argmin(slot_end, axis=1).astype(jnp.int32)
+        cslot = _argmin(slot_end)
         cu = _pick(slot_user, cslot)
         infl_cu = _pick(inflight, cu) - 1
         stage_done = (_pick(pending, cu) == 0) & (infl_cu == 0)
@@ -192,7 +246,7 @@ def _event_kernel(nm_ref, nr_ref, cap_ref, nea_ref, ma_ref, ra_ref, tm_ref,
                            _pick(arrival, cu))
         c_arrival = _place(arrival, cu, jnp.where(job_done, INF, arr_cu))
         resp = t_slot - _pick(job_start, cu)
-        td_i = jax.lax.dynamic_slice_in_dim(td, i, 1, 1)[:, 0]
+        td_i = td_ref[pl.ds(j, 1), :]
         new_think = t_slot + td_i * tm        # oracle: t_slot + e*think_ms
         c_think = _place(think_end, cu, jnp.where(
             job_done, new_think, _pick(think_end, cu)))
@@ -200,23 +254,20 @@ def _event_kernel(nm_ref, nr_ref, cap_ref, nea_ref, ma_ref, ra_ref, tm_ref,
         c_resp_sum = resp_sum + jnp.where(counted, resp, 0.0)
         c_resp_cnt = resp_cnt + jnp.where(counted, 1.0, 0.0)
         c_done = done_jobs + jnp.where(job_done, 1, 0)
-        c_slot_end = _place(slot_end, cslot, jnp.full((L,), INF))
-        c_slot_user = _place(slot_user, cslot,
-                             jnp.full((L,), -1, jnp.int32))
+        c_slot_end = _place(slot_end, cslot, INF)
+        c_slot_user = _place(slot_user, cslot, -1)
 
         # think end -> submit job (fork maps)
-        tu = jnp.argmin(think_end, axis=1).astype(jnp.int32)
-        t_phase = _place(phase, tu, jnp.ones((L,), jnp.int32))
+        tu = _argmin(think_end)
+        t_phase = _place(phase, tu, 1)
         t_pending = _place(pending, tu, nm)
         t_arrival = _place(arrival, tu, t_think)
         t_jobstart = _place(job_start, tu, t_think)
-        t_think_end = _place(think_end, tu, jnp.full((L,), INF))
+        t_think_end = _place(think_end, tu, INF)
 
-        def sel(cur, d, c, t):
-            bd, bc, bt = b_dispatch, b_complete, b_think
-            if cur.ndim == 2:
-                bd, bc, bt = bd[:, None], bc[:, None], bt[:, None]
-            return jnp.where(bd, d, jnp.where(bc, c, jnp.where(bt, t, cur)))
+        def sel(cur, on_dispatch, on_complete, on_think):
+            return jnp.where(b_dispatch, on_dispatch, jnp.where(
+                b_complete, on_complete, jnp.where(b_think, on_think, cur)))
 
         return (sel(now, now, t_slot, t_think),
                 sel(slot_end, d_slot_end, c_slot_end, slot_end),
@@ -231,21 +282,15 @@ def _event_kernel(nm_ref, nr_ref, cap_ref, nea_ref, ma_ref, ra_ref, tm_ref,
                 sel(resp_cnt, resp_cnt, c_resp_cnt, resp_cnt),
                 sel(done_jobs, done_jobs, c_done, done_jobs))
 
-    init = (jnp.zeros((L,), jnp.float32),                       # now
-            jnp.full((L, max_slots), INF),                      # slot_end
-            jnp.full((L, max_slots), -1, jnp.int32),            # slot_user
-            think0_ref[...],                                    # think_end
-            jnp.zeros((L, h_users), jnp.int32),                 # phase
-            jnp.zeros((L, h_users), jnp.int32),                 # pending
-            jnp.zeros((L, h_users), jnp.int32),                 # inflight
-            jnp.full((L, h_users), INF),                        # arrival
-            jnp.zeros((L, h_users), jnp.float32),               # job_start
-            jnp.zeros((L,), jnp.float32),                       # resp_sum
-            jnp.zeros((L,), jnp.float32),                       # resp_cnt
-            jnp.zeros((L,), jnp.int32))                         # done_jobs
-    out = jax.lax.fori_loop(0, n_events, step, init)
-    sum_ref[...] = out[9]
-    cnt_ref[...] = out[10]
+    out = jax.lax.fori_loop(0, chunk, step,
+                            tuple(ref[...] for ref in state_refs))
+    for ref, val in zip(state_refs, out):
+        ref[...] = val
+
+    @pl.when(c == pl.num_programs(1) - 1)
+    def _emit():
+        out_ref[0:1, :] = out[9]
+        out_ref[1:2, :] = out[10]
 
 
 # ---------------------------------------------------------------------------
@@ -255,54 +300,54 @@ def _event_kernel(nm_ref, nr_ref, cap_ref, nea_ref, ma_ref, ra_ref, tm_ref,
 def qn_event_fwd(n_map, n_reduce, m_avg, r_avg, think_ms, slots_cap, seed,
                  n_events_active, m_samples=None, r_samples=None, *,
                  h_users: int, max_slots: int, n_events: int,
-                 warmup_jobs: int, lane_block: int = LANE_BLOCK,
-                 interpret: bool = True):
+                 warmup_jobs: int, interpret: bool):
     """Drop-in for ``qn_sim._sim_batch_jit``: all per-lane parameters are
     ``(B,)`` arrays, replay sample lists (when given) are shared across the
     batch.  Returns ``(mean_resp, resp_cnt)`` per lane, bit-identical (in
     interpret mode) to the ``lax.scan`` oracle."""
     B = n_map.shape[0]
-    L = min(lane_block, B)
+    L = min(B, LANE_TILE)
+    lane_pad = (-B) % L
+    chunk = min(EVENT_CHUNK, -(-n_events // 8) * 8)
+    event_pad = (-n_events) % chunk
 
     streams = functools.partial(event_streams, h_users=h_users,
                                 n_events=n_events, m_samples=m_samples,
                                 r_samples=r_samples)
-    think0, st_m, st_r, td = jax.vmap(streams)(
+    think0, st_m, st_r, td = jax.vmap(streams, out_axes=1)(
         m_avg, r_avg, think_ms, seed, n_events_active)
 
-    m_avg = jnp.asarray(m_avg, jnp.float32)
-    r_avg = jnp.asarray(r_avg, jnp.float32)
-    think_ms = jnp.asarray(think_ms, jnp.float32)
-    pad = (-B) % L
-    if pad:
+    ip = jnp.stack([n_map, n_reduce, slots_cap, n_events_active]) \
+        .astype(jnp.int32)
+    fp = jnp.stack([m_avg, r_avg, think_ms]).astype(jnp.float32)
+    if lane_pad:
         # pure-padding lanes: zero active events -> untouched state,
-        # resp_cnt == 0; dropped below
-        p1 = lambda x: jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
-        n_map, n_reduce, slots_cap, n_events_active, m_avg, r_avg, \
-            think_ms = map(p1, (n_map, n_reduce, slots_cap,
-                                n_events_active, m_avg, r_avg, think_ms))
-        think0, st_m, st_r, td = map(p1, (think0, st_m, st_r, td))
-        slots_cap = slots_cap.at[B:].set(1)    # keep slot mask well-formed
+        # resp_cnt == 0; dropped below.  slots_cap 1 keeps the slot mask
+        # well-formed.
+        ip, fp, think0 = (jnp.pad(x, ((0, 0), (0, lane_pad)))
+                          for x in (ip, fp, think0))
+        ip = ip.at[2, B:].set(1)
+    if lane_pad or event_pad:
+        # padded events lie past every lane's n_events_active: no-op steps
+        st_m, st_r, td = (jnp.pad(x, ((0, event_pad), (0, lane_pad)))
+                          for x in (st_m, st_r, td))
 
-    grid = ((B + pad) // L,)
-    vec = pl.BlockSpec((L,), lambda i: (i,))
-    tab = pl.BlockSpec((L, n_events), lambda i: (i, 0))
+    layout = _state_layout(L, max_slots, h_users)
     kernel = functools.partial(
-        _event_kernel, h_users=h_users, max_slots=max_slots,
-        n_events=n_events, warmup_jobs=warmup_jobs,
-        replay=m_samples is not None)
-    resp_sum, resp_cnt = pl.pallas_call(
+        _event_kernel, layout=layout, max_slots=max_slots, chunk=chunk,
+        warmup_jobs=warmup_jobs, replay=m_samples is not None)
+    rows = lambda n: pl.BlockSpec((n, L), lambda b, c: (0, b))
+    table = pl.BlockSpec((chunk, L), lambda b, c: (c, b))
+    out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[vec, vec, vec, vec, vec, vec, vec,
-                  pl.BlockSpec((L, h_users), lambda i: (i, 0)),
-                  tab, tab, tab],
-        out_specs=[vec, vec],
-        out_shape=[jax.ShapeDtypeStruct((B + pad,), jnp.float32),
-                   jax.ShapeDtypeStruct((B + pad,), jnp.float32)],
+        grid=((B + lane_pad) // L, (n_events + event_pad) // chunk),
+        in_specs=[rows(4), rows(3), rows(h_users), table, table, table],
+        out_specs=rows(2),
+        out_shape=jax.ShapeDtypeStruct((2, B + lane_pad), jnp.float32),
+        scratch_shapes=[pltpu.VMEM(shape, dt) for shape, dt, _ in layout],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(n_map.astype(jnp.int32), n_reduce.astype(jnp.int32),
-      slots_cap.astype(jnp.int32), n_events_active.astype(jnp.int32),
-      m_avg, r_avg, think_ms, think0, st_m, st_r, td)
-    resp_sum, resp_cnt = resp_sum[:B], resp_cnt[:B]
+    )(ip, fp, think0, st_m, st_r, td)
+    resp_sum, resp_cnt = out[0, :B], out[1, :B]
     return resp_sum / jnp.maximum(resp_cnt, 1.0), resp_cnt

@@ -2,8 +2,9 @@
 
 ``sim_batch`` is signature-compatible with ``qn_sim._sim_batch_jit`` (the
 ``lax.scan`` oracle) and is what ``qn_sim.response_time_batch`` dispatches
-to under ``impl="pallas"``.  Interpret mode on CPU (the tier-1 CI path,
-bit-exact vs the oracle), native Pallas on TPU.
+to under ``impl="pallas"``.  ``kernels.interpret_mode()`` decides how it
+runs: interpreted on CPU (the tier-1 path, bit-exact vs the oracle),
+compiled on TPU.
 
 The public wrapper opens a ``kernel:qn_event`` telemetry span around the
 jitted launch (counted once per dispatch, not per trace) and names the
@@ -16,12 +17,9 @@ from functools import partial
 
 import jax
 
+from repro.kernels import interpret_mode
 from repro.kernels.qn_event import kernel
 from repro.obs import trace as _obs_trace
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("h_users", "max_slots", "n_events",
@@ -34,7 +32,7 @@ def _sim_batch_jit(n_map, n_reduce, m_avg, r_avg, think_ms, slots_cap, seed,
             n_map, n_reduce, m_avg, r_avg, think_ms, slots_cap, seed,
             n_events_active, m_samples, r_samples,
             h_users=h_users, max_slots=max_slots, n_events=n_events,
-            warmup_jobs=warmup_jobs, interpret=not _on_tpu())
+            warmup_jobs=warmup_jobs, interpret=interpret_mode())
 
 
 def sim_batch(n_map, n_reduce, m_avg, r_avg, think_ms, slots_cap, seed,

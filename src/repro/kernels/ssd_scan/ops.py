@@ -7,17 +7,14 @@ from functools import partial
 
 import jax
 
+from repro.kernels import interpret_mode
 from repro.kernels.ssd_scan import kernel, ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(5,))
 def ssd(x, dt, A, B_, C_, chunk: int = 128):
     return kernel.ssd_fwd(x, dt, A, B_, C_, chunk=chunk,
-                          interpret=not _on_tpu())
+                          interpret=interpret_mode())
 
 
 def _fwd(x, dt, A, B_, C_, chunk):

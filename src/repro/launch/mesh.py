@@ -1,11 +1,15 @@
 """Production meshes.  Functions, not module constants, so importing this
 module never touches jax device state (the dry-run sets the 512-device
-XLA flag before any jax initialization)."""
+XLA flag before any jax initialization).
+
+Mesh axes are GSPMD-style (``AxisType.Auto``): the model stack's sharding
+rules constrain and the compiler places, which ``jax.make_mesh``'s default
+Explicit axes do not allow."""
 from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -13,7 +17,8 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     Multi-pod: 2 pods x 256 = 512 chips (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(*, data: int = 0, model: int = 1) -> Mesh:
@@ -39,7 +44,8 @@ def make_local_mesh(*, data: int = 0, model: int = 1) -> Mesh:
         raise ValueError(
             f"mesh shape ({data}, {model}) needs {data * model} devices "
             f"but only {n} are available")
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_lanes_mesh(shards: int = 0) -> Mesh:

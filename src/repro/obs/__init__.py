@@ -6,7 +6,6 @@ the solve → fusion → kernel stack.  See docs/observability.md.
 """
 from .compile import (  # noqa: F401
     compile_stats,
-    enable_persistent_cache,
     reset_compile_stats,
 )
 from .export import (  # noqa: F401

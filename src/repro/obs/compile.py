@@ -15,16 +15,20 @@ thread-local flag marks the next duration event as a cache hit rather
 than a real compile.
 
 ``install()`` (idempotent, called on ``repro.core.qn_sim`` import so every
-entry point is covered) also enables JAX's persistent compilation cache
-when ``REPRO_COMPILE_CACHE`` names a directory: repeat runs and CI then
-start warm — a warm second solve of a same-class problem reports 0 new
-compiles (regression-tested in ``tests/test_shapes.py``; asserted by the
-CI compile-budget smoke).  See docs/performance.md.
+entry point is covered) also turns on JAX's persistent compilation cache:
+in ``$JAX_COMPILATION_CACHE_DIR`` when that is set (JAX reads the variable
+itself, and no other directory is set here), else in the fixed
+``<checkout>/.jax-cache``.  The path is part of what makes a later run hit,
+so it never moves.  Repeat runs then start warm — a warm second solve of a
+same-class problem reports 0 new compiles (regression-tested in
+``tests/test_shapes.py``; asserted by the CI compile-budget smoke).  See
+docs/performance.md.
 """
 from __future__ import annotations
 
 import os
 import threading
+from pathlib import Path
 
 from repro.obs import metrics as _obs_metrics
 
@@ -38,6 +42,9 @@ _CACHE_HITS = _REG.counter("qn.compile_cache_hits",
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+# <checkout>/.jax-cache: this file is <checkout>/src/repro/obs/compile.py
+DEFAULT_CACHE_DIR = str(Path(__file__).resolve().parents[3] / ".jax-cache")
 
 _tls = threading.local()
 _installed = False
@@ -62,38 +69,24 @@ def _on_duration(event: str, duration_secs: float, **kw) -> None:
             _COMPILE_MS.inc(round(duration_secs * 1000))
 
 
-def enable_persistent_cache(path: str) -> None:
-    """Point JAX's persistent compilation cache at ``path`` and drop the
-    min-time/min-size thresholds so every executable is cached (the
-    simulator's programs are small; a cold CI run wants all of them)."""
-    import jax
-    jax.config.update("jax_compilation_cache_dir", str(path))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-
-
-def install() -> bool:
-    """Register the monitoring listeners once per process and, when
-    ``$REPRO_COMPILE_CACHE`` is set, enable the persistent cache.  Safe on
-    jax builds without ``jax.monitoring`` (returns False)."""
+def install() -> None:
+    """Register the monitoring listeners and turn on the persistent
+    compilation cache, once per process.  The cache drops JAX's
+    min-time/min-size thresholds so every executable is kept (the
+    simulator's programs are small; a cold run wants all of them)."""
     global _installed
     with _install_lock:
         if _installed:
-            return True
-        try:
-            from jax import monitoring
-            monitoring.register_event_listener(_on_event)
-            monitoring.register_event_duration_secs_listener(_on_duration)
-        except Exception:
-            return False
-        cache_dir = os.environ.get("REPRO_COMPILE_CACHE")
-        if cache_dir:
-            try:
-                enable_persistent_cache(cache_dir)
-            except Exception:      # cache is an optimization, never fatal
-                pass
+            return
+        import jax
+        from jax import monitoring
+        monitoring.register_event_listener(_on_event)
+        monitoring.register_event_duration_secs_listener(_on_duration)
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
         _installed = True
-        return True
 
 
 def compile_stats() -> dict:

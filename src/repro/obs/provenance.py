@@ -1,8 +1,10 @@
 """Build-provenance stamp shared by benchmarks and the flight recorder.
 
 One dict answers "which commit/backend produced this artifact?": git SHA,
-jax version + device count, platform, and the two env knobs that change
-the numbers (``REPRO_QN_IMPL``, ``REPRO_SHARD``).  Lives in ``obs`` (not
+jax version, the device JAX computes on (``device``: platform,
+``device_kind`` and count, as ``jax.devices()`` reports them), the host
+OS, and the two choices that change the numbers (the batch simulator
+``qn_impl`` and ``REPRO_SHARD``).  Lives in ``obs`` (not
 ``benchmarks/``) so library code — recorder dumps, the ``/statz``
 endpoint — can stamp artifacts without importing the benchmark harness;
 ``benchmarks/common.provenance()`` is now a re-export of this.
@@ -35,10 +37,17 @@ def provenance() -> dict:
         pass
     jax_version = None
     devices = None
+    device = None
+    qn_impl = None
     try:
         import jax
         jax_version = jax.__version__
-        devices = len(jax.devices())
+        devs = jax.devices()
+        devices = len(devs)
+        device = {"platform": devs[0].platform,
+                  "kind": devs[0].device_kind, "count": devices}
+        from repro.core import qn_sim
+        qn_impl = qn_sim.default_impl()
     except Exception:
         pass
     shard = None
@@ -52,8 +61,9 @@ def provenance() -> dict:
         "jax": jax_version,
         "platform": _platform.platform(),
         "python": _platform.python_version(),
-        "qn_impl": os.environ.get("REPRO_QN_IMPL", "jnp"),
+        "qn_impl": qn_impl,
         "devices": devices,
+        "device": device,
         "repro_shard": os.environ.get("REPRO_SHARD", "auto"),
         "shard": shard,
     }

@@ -84,6 +84,11 @@ class EvalCache:
             self._d[key] = float(value)
         _CACHE["puts"].inc()
 
+    def snapshot(self) -> Dict[CacheKey, float]:
+        """A copy of every cached point estimate."""
+        with self._lock:
+            return dict(self._d)
+
     def __len__(self) -> int:
         return len(self._d)
 

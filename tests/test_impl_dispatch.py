@@ -8,6 +8,7 @@ inside ``run``) are driven end to end under ``impl="jnp"`` and
 counted at the marshaling layer, before the backend dispatch, so a kernel
 swap can never silently alter the optimizer's search path or its dispatch
 budget."""
+import jax
 import pytest
 
 from repro import obs
@@ -15,6 +16,7 @@ from repro.cloud import PrivateCloud, homogeneous_hosts
 from repro.core import qn_sim
 from repro.core.optimizer import DSpace4Cloud
 from repro.core.problem import ApplicationClass, JobProfile, Problem, VMType
+from repro.kernels import interpret_mode
 
 STEADY = VMType(name="steady", cores=2, sigma=0.05, pi=0.20)
 TURBO = VMType(name="turbo", cores=2, sigma=0.0425, pi=0.17)
@@ -106,6 +108,22 @@ def test_explicit_impl_overrides_process_default():
     finally:
         qn_sim.set_default_impl(old)
     assert list(got_default) == list(got_jnp)    # parity, different backends
+
+
+@pytest.mark.parametrize("platform,interpret,impl",
+                         [("cpu", True, "jnp"), ("tpu", False, "pallas")])
+def test_platform_decides_interpret_mode_and_default_impl(
+        monkeypatch, platform, interpret, impl):
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    monkeypatch.setattr(qn_sim, "_DEFAULT_IMPL", None)   # $REPRO_QN_IMPL unset
+    assert interpret_mode() is interpret
+    assert qn_sim.default_impl() == impl
+
+
+def test_other_platforms_have_no_kernel_path(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        interpret_mode()
 
 
 if __name__ == "__main__":  # pragma: no cover

@@ -85,7 +85,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import json
 import jax, jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.distributed.pipeline import (PipelineConfig, pipeline_forward,
     split_microbatches, merge_microbatches, stack_stage_params)
 
@@ -101,7 +101,9 @@ ref = x
 for p in per_stage:
     ref = stage_fn(p, ref)
 
-mesh = jax.make_mesh((4,), ("stage",))
+# GSPMD-style (Auto) axes: pipeline_forward leaves placement to the
+# compiler, which jax.make_mesh's default Explicit axes do not
+mesh = jax.make_mesh((4,), ("stage",), axis_types=(AxisType.Auto,))
 stacked = jax.device_put(stack_stage_params(per_stage),
                          NamedSharding(mesh, P("stage")))
 cfg = PipelineConfig(n_stages=4, n_microbatches=8)

@@ -9,12 +9,16 @@ see kernels/qn_event/kernel.py).  Degenerate shapes ride along: all-padding
 lanes (zero logical event budget), single-slot lanes, non-pow2 candidate
 counts that force padded vmap lanes.
 """
+import functools
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import qn_sim
+from repro.kernels.qn_event import kernel as qn_kernel
 from repro.kernels.qn_event import ops as qn_event_ops
 from repro.kernels.qn_event import ref as qn_event_ref
 
@@ -102,6 +106,28 @@ def test_direct_sim_batch_bitwise_with_zero_budget_lanes():
     assert np.array_equal(np.asarray(cnt_k), np.asarray(cnt_o))
     assert np.array_equal(np.asarray(mean_k), np.asarray(mean_o))
     assert float(cnt_k[0]) == 0.0 and float(cnt_k[4]) == 0.0
+
+
+def test_streamed_event_blocks_and_lane_blocks_bitwise(monkeypatch):
+    """Small tiles force several lane blocks and several streamed event
+    blocks, each axis with a padded tail: the lane state carried in
+    scratch across event blocks must reproduce the oracle's one scan."""
+    monkeypatch.setattr(qn_kernel, "LANE_TILE", 8)
+    monkeypatch.setattr(qn_kernel, "EVENT_CHUNK", 24)
+    budget = qn_sim.padded_event_budget(BASE["n_map"], BASE["n_reduce"],
+                                        min_jobs=8, warmup_jobs=2)
+    assert budget % 24 and budget > 3 * 24
+    budgets = [budget, budget // 3, 0, budget // 2, budget] * 4 \
+        + [budget // 5]                          # 21 lanes: 3 blocks of 8
+    slots = [1 + i % 5 for i in range(len(budgets))]
+    args, statics = _direct_args(budgets, slots)
+    fwd = jax.jit(functools.partial(qn_kernel.qn_event_fwd, **statics,
+                                    interpret=True))
+    mean_k, cnt_k = fwd(*args)
+    mean_o, cnt_o = qn_event_ref.sim_batch(*args, **statics)
+    assert np.array_equal(np.asarray(cnt_k), np.asarray(cnt_o))
+    assert np.array_equal(np.asarray(mean_k), np.asarray(mean_o))
+    assert float(cnt_k[2]) == 0.0 and float(cnt_k[0]) > 0.0
 
 
 def test_single_slot_single_user_degenerate():

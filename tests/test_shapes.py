@@ -190,8 +190,7 @@ def test_bucket_padding_counted_separately(restore_grid):
 
 # --------------------------------------------------- warm path: 0 compiles
 def test_warm_resubmission_zero_compiles():
-    if not obs_compile.install():
-        pytest.skip("jax.monitoring unavailable")
+    obs_compile.install()                    # listeners on (idempotent)
 
     def solve(slots):
         return qn_sim.response_time_batch(
@@ -211,3 +210,16 @@ def test_warm_resubmission_zero_compiles():
     c1 = obs_compile.compile_stats()
     assert c1["compiles"] == c0["compiles"], \
         f"warm path recompiled: {c1['compiles'] - c0['compiles']}"
+
+
+def test_persistent_cache_dir_is_the_env_or_the_checkout():
+    import os
+
+    import jax
+    obs_compile.install()
+    want = os.environ.get("JAX_COMPILATION_CACHE_DIR") \
+        or obs_compile.DEFAULT_CACHE_DIR
+    assert jax.config.jax_compilation_cache_dir == want
+    assert obs_compile.DEFAULT_CACHE_DIR.endswith(os.sep + ".jax-cache")
+    assert os.path.isdir(os.path.join(
+        os.path.dirname(obs_compile.DEFAULT_CACHE_DIR), "src", "repro"))

@@ -359,6 +359,16 @@ def test_recorder_events_carry_wall_tenant_and_dump_provenance(tmp_path):
     assert json.loads(p.read_text())["provenance"] == dump["provenance"]
 
 
+def test_provenance_stamps_the_device():
+    import jax
+    devs = jax.devices()
+    prov = obs.provenance()
+    assert prov["device"] == {"platform": devs[0].platform,
+                              "kind": devs[0].device_kind,
+                              "count": len(devs)}
+    assert prov["qn_impl"] == qn_sim.default_impl()
+
+
 # ------------------------------------------------------ regression sentinel
 
 def _bench_doc(dispatches=8, wall=2.0, parity=True):
