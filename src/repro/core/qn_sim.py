@@ -24,6 +24,7 @@ against the detailed trace-replay simulator in ``cluster_sim.py``).
 from __future__ import annotations
 
 import os
+import time
 from dataclasses import dataclass
 from functools import partial
 from typing import Tuple
@@ -387,9 +388,9 @@ _QN_SHARD = {k: _REG.counter(f"qn.shard_{k}") for k in
              ("padded_lanes", "padded_events")}
 _QN_DEVICES = _REG.gauge(
     "qn.devices", help="lane shards (devices) of the last fused dispatch")
-_QN_WASTE = _REG.gauge(
-    "qn.padded_waste_ratio",
-    help="1 - events_useful/events_total over process lifetime")
+_QN_SYNC_US = _REG.counter(
+    "qn.sync_wait_us",
+    help="host time blocked fetching batch results from the device [us]")
 
 
 def _count_dispatch(n: int = 1, *, lanes: int = None, padded_lanes: int = 0,
@@ -418,9 +419,6 @@ def _count_dispatch(n: int = 1, *, lanes: int = None, padded_lanes: int = 0,
         _QN_SHARD["padded_lanes"].inc(shard_padded_lanes)
         _QN_SHARD["padded_events"].inc(shard_padded_events)
         _QN_DEVICES.set(devices)
-        tot = _QN_COUNTERS["events_total"].value
-        if tot:
-            _QN_WASTE.set(1.0 - _QN_COUNTERS["events_useful"].value / tot)
 
 
 def padding_stats() -> dict:
@@ -470,7 +468,7 @@ def sim_stats() -> dict:
 
 def reset_sim_stats() -> None:
     """Zero ALL simulator counters (dispatches, lanes, padded_lanes,
-    events_total, events_useful) and the derived waste-ratio gauge.  This
+    events_total, events_useful, and the bucket and shard padding).  This
     is the one reset for per-run accounting; ``reset_dispatch_count`` is a
     back-compat alias."""
     with _REG.lock:
@@ -480,7 +478,6 @@ def reset_sim_stats() -> None:
             c.reset()
         for c in _QN_SHARD.values():
             c.reset()
-        _QN_WASTE.reset()
 
 
 reset_dispatch_count = reset_sim_stats
@@ -613,7 +610,7 @@ class PendingBatch:
 
     def resolve(self) -> np.ndarray:
         if self._out is None:
-            return self._finish(*jax.device_get((self._mean, self._cnt)))
+            return self._finish(*_device_get((self._mean, self._cnt), 1))
         return self._out
 
     @classmethod
@@ -624,6 +621,17 @@ class PendingBatch:
         return pb
 
 
+def _device_get(tree, batches: int):
+    """``jax.device_get`` of ``batches`` handles' arrays: the host waits
+    here for the device, so the wait is spanned (``resolve``) and counted
+    (``qn.sync_wait_us``)."""
+    with _obs_trace.span("resolve", cat="qn", batches=batches):
+        t0 = time.perf_counter_ns()
+        out = jax.device_get(tree)
+        _QN_SYNC_US.inc((time.perf_counter_ns() - t0) // 1000)
+    return out
+
+
 def resolve_batches(batches) -> list:
     """Resolve many ``PendingBatch`` handles with ONE ``jax.device_get``
     (one host sync per scheduling round instead of one per fusion group).
@@ -631,7 +639,7 @@ def resolve_batches(batches) -> list:
     batches = list(batches)
     todo = [b for b in batches if b._out is None]
     if todo:
-        fetched = jax.device_get([(b._mean, b._cnt) for b in todo])
+        fetched = _device_get([(b._mean, b._cnt) for b in todo], len(todo))
         for b, (m, c) in zip(todo, fetched):
             b._finish(m, c)
     return [b._out for b in batches]
@@ -758,8 +766,8 @@ def response_time_batch(n_map, n_reduce, m_avg, r_avg, think_ms,
         jnp.asarray(rep(n_ev), jnp.int32))
     with _obs_trace.span(f"kernel:{impl or default_impl()}", cat="kernel",
                          lanes=C_pad * R, candidates=C,
-                         scan_len=scan_len, replay=ms is not None,
-                         devices=shards,
+                         scan_len=scan_len, max_slots=max_slots,
+                         replay=ms is not None, devices=shards,
                          shard_lanes=C_pad * R // shards):
         if shards > 1:
             mean, cnt = _partition.shard_call(

@@ -314,8 +314,11 @@ def qn_event_fwd(n_map, n_reduce, m_avg, r_avg, think_ms, slots_cap, seed,
     streams = functools.partial(event_streams, h_users=h_users,
                                 n_events=n_events, m_samples=m_samples,
                                 r_samples=r_samples)
-    think0, st_m, st_r, td = jax.vmap(streams, out_axes=1)(
-        m_avg, r_avg, think_ms, seed, n_events_active)
+    # the draw tables are built by XLA outside the kernel; the scope names
+    # their device ops apart from the kernel's in a profile
+    with jax.named_scope("qn_event_draws"):
+        think0, st_m, st_r, td = jax.vmap(streams, out_axes=1)(
+            m_avg, r_avg, think_ms, seed, n_events_active)
 
     ip = jnp.stack([n_map, n_reduce, slots_cap, n_events_active]) \
         .astype(jnp.int32)

@@ -3,7 +3,9 @@
 A :class:`Tracer` records a tree of timed spans per process:
 ``solve → tier (kkt/amva/qn) → race_round → fused_dispatch →
 kernel:{jnp,pallas} → kernel:qn_event`` (service runs add
-``service.run → service_round → flush`` above the dispatch).  Export is
+``service.run → service_round → flush`` above the dispatch, with
+``resolve`` under ``flush``, ``advance`` beside it, and ``submit`` and
+``admit`` around the intake and the admission scan).  Export is
 Chrome trace-event JSON (``to_chrome()``/``save()``) loadable in Perfetto
 or ``chrome://tracing``; ``validate_chrome_trace`` checks the schema that
 tests and the CI traced-solve smoke assert against.
@@ -25,6 +27,12 @@ When jax is importable and the tracer is created with
 ``jax_annotations=True`` (the default), every span also opens a
 ``jax.profiler.TraceAnnotation`` so fused dispatches and Pallas kernel
 launches carry the same labels inside an XLA profile.
+
+Spans are stamped in microseconds on the host's real-time clock
+(``time.time_ns()``), the clock the JAX profiler stamps its host events
+with: a span's ``ts_us`` is its annotation's start in an XPlane (the
+plane's ``profile_start_time`` plus the event's offset), so the records
+and the ``save()`` export overlay a device trace.
 """
 from __future__ import annotations
 
@@ -65,7 +73,6 @@ class Tracer:
         self.dropped = 0
         self.max_spans = max_spans
         self.jax_annotations = jax_annotations and _JaxAnnotation is not None
-        self._t0 = time.perf_counter()
         self._lock = threading.Lock()
         self._sid = itertools.count(1)
         self._local = threading.local()
@@ -77,21 +84,22 @@ class Tracer:
             st = self._local.stack = []
         return st
 
-    def _now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
+    @staticmethod
+    def _now_us() -> float:
+        return time.time_ns() / 1e3
 
     @contextmanager
     def span(self, name: str, *, cat: str = "repro",
              **args: Any) -> Iterator[Span]:
         stack = self._stack()
         parent = stack[-1] if stack else None
+        ann = (_JaxAnnotation(name) if self.jax_annotations else None)
         s = Span(sid=next(self._sid),
                  parent=parent.sid if parent else None,
                  name=name, cat=cat, ts_us=self._now_us(), dur_us=0.0,
                  tid=threading.get_ident(), depth=len(stack),
                  args=dict(args))
         stack.append(s)
-        ann = (_JaxAnnotation(name) if self.jax_annotations else None)
         if ann is not None:
             ann.__enter__()
         try:
@@ -245,6 +253,16 @@ def span(name: str, *, cat: str = "repro", **args: Any):
     if t is None:
         return _noop()
     return t.span(name, cat=cat, **args)
+
+
+def annotate(**args: Any) -> None:
+    """Set ``args`` on this thread's innermost open span, for values known
+    only after the span opened; a no-op if tracing is off."""
+    t = _ACTIVE
+    if t is not None:
+        stack = t._stack()
+        if stack:
+            stack[-1].args.update(args)
 
 
 @contextmanager

@@ -25,8 +25,13 @@ cost about as many fused dispatches as the slowest single job alone
 Telemetry (docs/observability.md): every round appends one structured
 event to the flight recorder (a bounded ring buffer, dumped as JSON when
 a job fails or via ``dump_flight_recorder()``); round wall time feeds the
-``service.round_ms`` histogram; and when a tracer is installed the round
-opens a ``service_round`` span above the scheduler's ``flush``.
+``service.round_ms`` histogram and the ``service.round_us`` counter, the
+jobs active in it ``service.job_rounds``, and each admitted job's wait
+from submit to activation ``admission.queue_us``.  When a tracer is
+installed, ``submit`` spans the intake, ``admit`` the admission scan
+(activation and the analytic seed nest under it), and the round opens a
+``service_round`` span above the scheduler's ``flush`` and one
+``advance`` span per job, around delivering its results and retiring it.
 """
 from __future__ import annotations
 
@@ -55,9 +60,13 @@ _ROUND_MS = _REG.histogram(
 _ROUNDS = _REG.counter("service.rounds")
 _JOBS_DONE = _REG.counter("service.jobs_finished")
 _JOBS_FAILED = _REG.counter("service.jobs_failed")
-_JOB_WALL_MS = _REG.gauge(
-    "service.job_wall_ms",
-    help="queue-to-settle wall time of the tenant's last finished job")
+_JOB_ROUNDS = _REG.counter(
+    "service.job_rounds", help="jobs active in each counted round, summed")
+_ROUND_US = _REG.counter(
+    "service.round_us", help="wall time of the counted rounds [us]")
+_QUEUE_US = _REG.counter(
+    "admission.queue_us",
+    help="submit-to-activation wait of the admitted jobs, summed [us]")
 _PADDED_EVENTS = _REG.counter(
     "service.padded_events",
     help="padding-waste events attributed to the tenant's dispatches")
@@ -115,55 +124,58 @@ class SolverService:
         private cluster — overriding the problem document's own
         ``deployment`` field; such jobs are also admitted against the
         controller's physical-core budget."""
-        kw = dict(min_jobs=min_jobs, warmup_jobs=warmup_jobs,
-                  replications=replications, seed=seed)
-        if isinstance(problem, str):
-            problem, overrides = parse_submission(problem)
-            tag = overrides.pop("tag", tag)
-            window = overrides.pop("window", window)
-            race = overrides.pop("race", race)
-            deployment = overrides.pop("deployment", deployment)
-            unknown = set(overrides) - set(kw)
-            if unknown:                   # reject cleanly at intake, not as
-                raise ValueError(         # a TypeError from SimSpec(**kw)
-                    f"unknown solver option(s) {sorted(unknown)}; valid: "
-                    f"{sorted(kw)} + ['window', 'race', 'tag', "
-                    f"'deployment']")
-            kw.update(overrides)
-        if deployment is None:
-            deployment = getattr(problem, "deployment", None)
-        spec = SimSpec(**kw)
-        job = Job(id=f"job-{next(self._seq):04d}", problem=problem,
-                  spec=spec, window=window or self.window,
-                  race=race, samples=samples, tag=tag,
-                  deployment=deployment)
-        job.events_estimate = estimate_job_events(
-            problem, window=job.window, min_jobs=spec.min_jobs,
-            warmup_jobs=spec.warmup_jobs, replications=spec.replications,
-            race=job.race)
-        job.cores_estimate = estimate_job_cores(problem, deployment)
-        self._jobs[job.id] = job
-        if self.admission.accept_submission(len(self._queue)):
-            self._queue.append(job.id)
-            self.recorder.record("submit", tenant=job.tenant, job=job.id,
-                                 tag=tag, classes=len(problem.classes),
-                                 events_estimate=job.events_estimate)
-        else:
-            job.state = JobState.SHED
-            job.finished_s = time.time()
-            self.recorder.record("shed", tenant=job.tenant, job=job.id,
-                                 at="submit", queue_len=len(self._queue))
+        with _obs_trace.span("submit", cat="service"):
+            kw = dict(min_jobs=min_jobs, warmup_jobs=warmup_jobs,
+                      replications=replications, seed=seed)
+            if isinstance(problem, str):
+                problem, overrides = parse_submission(problem)
+                tag = overrides.pop("tag", tag)
+                window = overrides.pop("window", window)
+                race = overrides.pop("race", race)
+                deployment = overrides.pop("deployment", deployment)
+                unknown = set(overrides) - set(kw)
+                if unknown:               # reject cleanly at intake, not
+                    raise ValueError(     # as a TypeError from SimSpec(**kw)
+                        f"unknown solver option(s) {sorted(unknown)}; "
+                        f"valid: {sorted(kw)} + ['window', 'race', 'tag', "
+                        f"'deployment']")
+                kw.update(overrides)
+            if deployment is None:
+                deployment = getattr(problem, "deployment", None)
+            spec = SimSpec(**kw)
+            job = Job(id=f"job-{next(self._seq):04d}", problem=problem,
+                      spec=spec, window=window or self.window,
+                      race=race, samples=samples, tag=tag,
+                      deployment=deployment)
+            _obs_trace.annotate(job=job.id, tenant=job.tenant)
+            job.events_estimate = estimate_job_events(
+                problem, window=job.window, min_jobs=spec.min_jobs,
+                warmup_jobs=spec.warmup_jobs,
+                replications=spec.replications, race=job.race)
+            job.cores_estimate = estimate_job_cores(problem, deployment)
+            self._jobs[job.id] = job
+            if self.admission.accept_submission(len(self._queue)):
+                self._queue.append(job.id)
+                self.recorder.record(
+                    "submit", tenant=job.tenant, job=job.id, tag=tag,
+                    classes=len(problem.classes),
+                    events_estimate=job.events_estimate)
+            else:
+                job.state = JobState.SHED
+                job.finished_s = time.time()
+                self.recorder.record("shed", tenant=job.tenant, job=job.id,
+                                     at="submit", queue_len=len(self._queue))
         return job.id
 
     # ----------------------------------------------------------- admission
-    def _admit(self) -> None:
+    def _admit(self) -> int:
         """FIFO admission: queued jobs are offered in submission order and
         the first DEFER verdict stops the scan — later submissions never
         jump an earlier waiting job.  Under continuous traffic this is what
         guarantees a deferred (e.g. oversize) job eventually sees the
         in-flight budget it is waiting for instead of starving behind a
-        stream of smaller newcomers."""
-        admitted_until = 0
+        stream of smaller newcomers.  Returns the number admitted."""
+        admitted_until = admitted = 0
         for i, jid in enumerate(self._queue):
             job = self._jobs[jid]
             verdict = self.admission.try_admit(jid, job.events_estimate,
@@ -171,6 +183,7 @@ class SolverService:
                                                tenant=job.tenant)
             if verdict == ADMIT:
                 self._activate(job)
+                admitted += 1
             elif verdict == SHED:
                 job.state = JobState.SHED
                 job.finished_s = time.time()
@@ -183,10 +196,15 @@ class SolverService:
                 break
             admitted_until = i + 1
         self._queue = self._queue[admitted_until:]
+        return admitted
 
     def _activate(self, job: Job) -> None:
         job.state = JobState.SOLVING
         job.started_s = time.time()
+        job.started_ns = time.perf_counter_ns()
+        queue_us = (job.started_ns - job.submitted_ns) // 1000
+        _QUEUE_US.inc(queue_us)
+        _QUEUE_US.labels(tenant=job.tenant).inc(queue_us)
         self.recorder.record("activate", tenant=job.tenant, job=job.id,
                              window=job.window, race=job.race)
         # the facade's own evaluator stays idle here: run_steps() proposes
@@ -209,12 +227,15 @@ class SolverService:
     # ------------------------------------------------------------ stepping
     def step(self) -> bool:
         """One cooperative scheduling round; True while work remains."""
-        t_round = time.perf_counter()
-        self._admit()
+        t_round = time.perf_counter_ns()
+        with _obs_trace.span("admit", cat="service",
+                             queued=len(self._queue)):
+            _obs_trace.annotate(admitted=self._admit())
         if not self._active:
             return bool(self._queue)
         self.rounds += 1
         _ROUNDS.inc()
+        _JOB_ROUNDS.inc(len(self._active))
 
         with _obs_trace.span("service_round", cat="service",
                              round=self.rounds, active=len(self._active)):
@@ -241,19 +262,22 @@ class SolverService:
             for jid in list(self._active):
                 job = self._jobs[jid]
                 results = {r.rid: r.result for r in requests[jid]}
-                try:
-                    job._pending = job._gen.send(results)
-                    advanced += 1
-                except StopIteration as stop:
-                    self._active.remove(jid)
-                    self._finish(job, stop.value)
-                    finished += 1
-                except Exception as e:
-                    self._active.remove(jid)
-                    self._fail(job, e)
-                    finished += 1
+                with _obs_trace.span("advance", cat="service", job=jid):
+                    try:
+                        job._pending = job._gen.send(results)
+                        advanced += 1
+                    except StopIteration as stop:
+                        self._active.remove(jid)
+                        self._finish(job, stop.value)
+                        finished += 1
+                    except Exception as e:
+                        self._active.remove(jid)
+                        self._fail(job, e)
+                        finished += 1
 
-        round_ms = (time.perf_counter() - t_round) * 1e3
+        round_ns = time.perf_counter_ns() - t_round
+        _ROUND_US.inc(round_ns // 1000)
+        round_ms = round_ns / 1e6
         _ROUND_MS.observe(round_ms)
         self.recorder.record(
             "round", n=self.rounds, active=advanced, finished=finished,
@@ -292,7 +316,6 @@ class SolverService:
         self.scheduler.forget_job(job.id)
         _JOBS_DONE.inc()
         _JOBS_DONE.labels(tenant=job.tenant).inc()
-        _JOB_WALL_MS.labels(tenant=job.tenant).set(job.wall_ms)
         self.slo.observe(job.tenant, report.slo, wall_ms=job.wall_ms)
         self.recorder.record("finish", tenant=job.tenant, job=job.id,
                              state=str(job.state),
@@ -307,7 +330,6 @@ class SolverService:
         self.scheduler.forget_job(job.id)
         _JOBS_FAILED.inc()
         _JOBS_FAILED.labels(tenant=job.tenant).inc()
-        _JOB_WALL_MS.labels(tenant=job.tenant).set(job.wall_ms)
         self.slo.observe(job.tenant, None, wall_ms=job.wall_ms,
                          failed=True)
         self.recorder.record("fail", tenant=job.tenant, job=job.id,

@@ -50,6 +50,10 @@ class Job:
     state: str = JobState.QUEUED
     submitted_s: float = field(default_factory=time.time)
     started_s: Optional[float] = None
+    # the same two instants on the monotonic clock, for the queue-time
+    # counter (admission.queue_us)
+    submitted_ns: int = field(default_factory=time.perf_counter_ns)
+    started_ns: Optional[int] = None
     finished_s: Optional[float] = None
     report: Optional[RunReport] = None
     error: Optional[str] = None

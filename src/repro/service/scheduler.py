@@ -148,55 +148,57 @@ class FusionScheduler:
         """Resolve every pending request: gather cache hits, fuse the
         misses into one device call per fusion group, fill ``req.result``
         for all requests, and return them."""
-        pending, self._pending = self._pending, []
-        rep = FlushReport()
+        with _obs_trace.span("flush", cat="fusion"):
+            pending, self._pending = self._pending, []
+            rep = FlushReport()
 
-        # point -> (prof, think, slots) by cache key, grouped by fusion key
-        todo: Dict[tuple, Dict[CacheKey, tuple]] = {}
-        keys: Dict[int, List[CacheKey]] = {}       # id(req) -> keys per nu
-        tenants: Dict[str, str] = {}               # job_id -> tenant label
-        for req in pending:
-            prof = req.cls.profile_for(req.vm)
-            digest, sdig = self._digest(req)
-            kind = workload_kind(prof)
-            fkey = (kind, req.cls.h_users, sdig, req.spec)
-            if kind == DAG and req.samples is not None:
-                # replay lanes share one (K, NS) sample array, so a replay
-                # group must also agree on the stage count — two tenants
-                # reusing one profiling run for different chain lengths
-                # must not land in the same program (non-replay DAG lanes
-                # pad freely and fuse across chain lengths)
-                fkey += (len(prof.stages),)
-            keys[id(req)] = kl = []
-            tenant = req.tenant or req.job_id
-            tenants[req.job_id] = tenant
-            tally = rep.per_job.setdefault(
-                req.job_id, {"points": 0, "cached": 0, "dispatched": 0,
-                             "deduped": 0})
-            for nu in req.nus:
-                ck: CacheKey = (digest, req.vm.name, int(nu), req.spec.seed)
-                kl.append(ck)
-                rep.points += 1
-                tally["points"] += 1
-                if self.cache.lookup(ck, tenant=tenant) is not None:
-                    rep.points_cached += 1
-                    tally["cached"] += 1
-                    continue
-                group = todo.setdefault(fkey, {})
-                if ck in group:
-                    # same-key miss already owned by an earlier requester
-                    # this round: fold into its lane, credit the dedup here
-                    rep.points_deduped += 1
-                    tally["deduped"] += 1
-                else:
-                    group[ck] = (prof, req.cls.think_ms,
-                                 int(nu) * req.vm.slots, req.samples)
-                    # first requester of the miss is charged the dispatch
-                    tally["dispatched"] += 1
-                    rep.points_dispatched += 1
+            # point -> (prof, think, slots) by cache key, grouped by fusion key
+            todo: Dict[tuple, Dict[CacheKey, tuple]] = {}
+            keys: Dict[int, List[CacheKey]] = {}       # id(req) -> keys per nu
+            tenants: Dict[str, str] = {}               # job_id -> tenant label
+            for req in pending:
+                prof = req.cls.profile_for(req.vm)
+                digest, sdig = self._digest(req)
+                kind = workload_kind(prof)
+                fkey = (kind, req.cls.h_users, sdig, req.spec)
+                if kind == DAG and req.samples is not None:
+                    # replay lanes share one (K, NS) sample array, so a replay
+                    # group must also agree on the stage count — two tenants
+                    # reusing one profiling run for different chain lengths
+                    # must not land in the same program (non-replay DAG lanes
+                    # pad freely and fuse across chain lengths)
+                    fkey += (len(prof.stages),)
+                keys[id(req)] = kl = []
+                tenant = req.tenant or req.job_id
+                tenants[req.job_id] = tenant
+                tally = rep.per_job.setdefault(
+                    req.job_id, {"points": 0, "cached": 0, "dispatched": 0,
+                                 "deduped": 0})
+                for nu in req.nus:
+                    ck: CacheKey = (digest, req.vm.name, int(nu),
+                                    req.spec.seed)
+                    kl.append(ck)
+                    rep.points += 1
+                    tally["points"] += 1
+                    if self.cache.lookup(ck, tenant=tenant) is not None:
+                        rep.points_cached += 1
+                        tally["cached"] += 1
+                        continue
+                    group = todo.setdefault(fkey, {})
+                    if ck in group:
+                        # same-key miss already owned by an earlier requester
+                        # this round: fold into its lane, credit the dedup here
+                        rep.points_deduped += 1
+                        tally["deduped"] += 1
+                    else:
+                        group[ck] = (prof, req.cls.think_ms,
+                                     int(nu) * req.vm.slots, req.samples)
+                        # first requester of the miss is charged the dispatch
+                        tally["dispatched"] += 1
+                        rep.points_dispatched += 1
 
-        with _obs_trace.span("flush", cat="fusion", groups=len(todo),
-                             points=rep.points, cached=rep.points_cached):
+            _obs_trace.annotate(groups=len(todo), points=rep.points,
+                                cached=rep.points_cached)
             # Phase 1 — async-dispatch every fusion group's device program
             # (marshaling the next group overlaps the device executing the
             # previous one); phase 2 — ONE coalesced host sync for the
@@ -224,28 +226,28 @@ class FusionScheduler:
                     for ck, t in zip(cks, ts):
                         self.cache.put(ck, float(t))
 
-        for req in pending:
-            req.result = np.array(
-                [self.cache.get(k) for k in keys[id(req)]], np.float64)
+            for req in pending:
+                req.result = np.array(
+                    [self.cache.get(k) for k in keys[id(req)]], np.float64)
 
-        self.fused_dispatches += rep.groups
-        self.points_dispatched += rep.points_dispatched
-        with _REG.lock:
-            _FUSION["groups"].inc(rep.groups)
-            _FUSION["points"].inc(rep.points)
-            _FUSION["points_dispatched"].inc(rep.points_dispatched)
-            _FUSION["points_cached"].inc(rep.points_cached)
-            _FUSION["points_deduped"].inc(rep.points_deduped)
-            for jid, tally in rep.per_job.items():
-                lbl = {"tenant": tenants[jid]}
-                _FUSION["points"].labels(**lbl).inc(tally["points"])
-                _FUSION["points_dispatched"].labels(**lbl).inc(
-                    tally["dispatched"])
-                _FUSION["points_cached"].labels(**lbl).inc(tally["cached"])
-                _FUSION["points_deduped"].labels(**lbl).inc(
-                    tally["deduped"])
-        self.last_flush = rep
-        return pending
+            self.fused_dispatches += rep.groups
+            self.points_dispatched += rep.points_dispatched
+            with _REG.lock:
+                _FUSION["groups"].inc(rep.groups)
+                _FUSION["points"].inc(rep.points)
+                _FUSION["points_dispatched"].inc(rep.points_dispatched)
+                _FUSION["points_cached"].inc(rep.points_cached)
+                _FUSION["points_deduped"].inc(rep.points_deduped)
+                for jid, tally in rep.per_job.items():
+                    lbl = {"tenant": tenants[jid]}
+                    _FUSION["points"].labels(**lbl).inc(tally["points"])
+                    _FUSION["points_dispatched"].labels(**lbl).inc(
+                        tally["dispatched"])
+                    _FUSION["points_cached"].labels(**lbl).inc(tally["cached"])
+                    _FUSION["points_deduped"].labels(**lbl).inc(
+                        tally["deduped"])
+            self.last_flush = rep
+            return pending
 
     def stats(self) -> dict:
         return {"fused_dispatches": self.fused_dispatches,

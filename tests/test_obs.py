@@ -10,7 +10,10 @@ exports as schema-valid Chrome trace-event JSON.  The registry's ``qn.*``
 counters ARE the ``sim_stats()`` store (one lock, one source of truth),
 and the flight recorder preserves the rounds leading up to a job failure.
 """
+import glob
 import json
+import statistics
+import time
 
 import pytest
 
@@ -21,7 +24,7 @@ from repro.core.problem import ApplicationClass, JobProfile, Problem, VMType
 from repro.obs.metrics import Counter, Histogram, MetricsRegistry, \
     counter_delta
 from repro.obs.recorder import FlightRecorder
-from repro.service import JobState, SolverService
+from repro.service import AdmissionController, JobState, SolverService
 
 STEADY = VMType(name="steady", cores=2, sigma=0.05, pi=0.20)
 TURBO = VMType(name="turbo", cores=2, sigma=0.0425, pi=0.17)
@@ -159,6 +162,120 @@ def test_tracer_bounds_spans_and_counts_drops():
     assert len(t.spans) == 2
     assert t.dropped == 3
     assert t.summary()["dropped"] == 3
+
+
+def _serialized_service_run(impl=None):
+    """Three tenants' jobs through a service whose admission budget makes
+    every job oversize, so they plan one at a time and the later ones wait
+    in the queue; returns ``(tracer, jobs, counter deltas)``."""
+    old = qn_sim.default_impl()
+    reg = obs.registry()
+    try:
+        if impl is not None:
+            qn_sim.set_default_impl(impl)
+        with obs.tracing() as t:
+            svc = SolverService(window=4, admission=AdmissionController(
+                max_inflight_events=1))
+            before = reg.snapshot()
+            for i in range(3):
+                svc.submit(_service_problem(deadline_ms=45_000.0 + 5e3 * i),
+                           min_jobs=6, replications=1, seed=3 + i,
+                           tag=f"tenant-{i}")
+            jobs = svc.run_until_complete()
+            delta = counter_delta(before, reg.snapshot())
+    finally:
+        qn_sim.set_default_impl(old)
+    assert all(j.state == JobState.DONE for j in jobs.values())
+    return t, jobs, delta
+
+
+def test_service_round_spans_nest_under_their_parents_and_name_the_job():
+    t, jobs, _ = _serialized_service_run(impl="pallas")
+    subs = t.by_name("submit")
+    assert sorted(s.args["job"] for s in subs) == sorted(jobs)
+    for s in subs:
+        assert t.chain(s) == ["submit"]
+        assert s.args["tenant"] == jobs[s.args["job"]].tenant
+    admits = t.by_name("admit")
+    assert all(t.chain(s) == ["service.run", "admit"] for s in admits)
+    assert sum(s.args["admitted"] for s in admits) == len(jobs)
+    assert max(s.args["queued"] for s in admits) == len(jobs)
+    # activation's analytic seed runs inside the admission scan
+    for s in t.by_name("tier:kkt"):
+        assert t.chain(s) == ["service.run", "admit", "tier:kkt"]
+    advances = t.by_name("advance")
+    assert {s.args["job"] for s in advances} == set(jobs)
+    assert all(t.chain(s) == ["service.run", "service_round", "advance"]
+               for s in advances)
+    resolves = t.by_name("resolve")
+    assert resolves
+    for s in resolves:
+        assert t.chain(s) == ["service.run", "service_round", "flush",
+                              "resolve"]
+        assert s.args["batches"] >= 1
+    for s in t.by_name("flush"):
+        assert {"groups", "points", "cached"} <= set(s.args)
+    # the dispatch chain the kernel's roofline reads is unchanged
+    kernels = t.by_name("kernel:qn_event")
+    assert kernels
+    for s in kernels:
+        assert t.chain(s)[-3:] == ["fused_dispatch", "kernel:pallas",
+                                   "kernel:qn_event"]
+        assert t.chain(s)[:3] == ["service.run", "service_round", "flush"]
+    outer = {s.sid: s for s in t.by_name("kernel:pallas")}
+    for s in kernels:
+        assert outer[s.parent].args["max_slots"] == s.args["max_slots"]
+
+
+def test_service_counters_reconcile_with_the_jobs():
+    t, jobs, d = _serialized_service_run()
+    waits = [(j.started_ns - j.submitted_ns) / 1e3 for j in jobs.values()]
+    assert max(waits) > 0
+    assert abs(d["admission.queue_us"] - sum(waits)) <= len(jobs)
+    for j in jobs.values():
+        assert d[f'admission.queue_us{{tenant="{j.tenant}"}}'] == \
+            (j.started_ns - j.submitted_ns) // 1000
+    assert d["admission.admit"] == len(jobs)
+    # one advance span per (job, round) in which the job was active
+    assert d["service.job_rounds"] == len(t.by_name("advance")) \
+        == sum(j.rounds for j in jobs.values())
+    assert d["service.rounds"] == len(t.by_name("service_round"))
+    assert d["service.round_us"] > 0
+    assert 0 <= d["qn.sync_wait_us"] <= d["service.round_us"]
+
+
+def test_tracer_stamps_spans_on_the_real_time_clock():
+    with obs.tracing(jax_annotations=False) as t:
+        before = time.time_ns()
+        with obs.span("probe"):
+            pass
+        after = time.time_ns()
+    (s,) = t.by_name("probe")
+    assert before / 1e3 <= s.ts_us <= s.ts_us + s.dur_us <= after / 1e3
+
+
+def test_span_starts_overlay_their_profiler_annotations(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.tracing() as t:
+            for i in range(20):
+                with obs.span(f"probe{i}"):
+                    time.sleep(1e-4)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    pd = ProfileData.from_file(path)
+    (env,) = [p for p in pd.planes if p.name == "Task Environment"]
+    t0_ns = dict(env.stats)["profile_start_time"]
+    starts = {e.name: t0_ns + e.start_ns for p in pd.planes
+              if p.name.startswith("/host:") for line in p.lines
+              for e in line.events if e.name.startswith("probe")}
+    gaps_us = [abs(s.ts_us - starts[s.name] / 1e3) for s in t.spans]
+    assert len(gaps_us) == 20
+    assert statistics.median(gaps_us) <= 50.0
 
 
 # ------------------------------------------------------------ chrome export
