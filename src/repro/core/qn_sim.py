@@ -365,11 +365,11 @@ def _batch_sim_fns(impl):
 
 # Counters live in the process-global metrics registry (repro.obs.metrics)
 # under the ``qn.`` prefix; the names below are the historical sim_stats
-# keys.  All five update atomically under the shared registry lock — the
+# keys.  All of them update atomically under the shared registry lock — the
 # same guarantee the old private _DISPATCH_LOCK gave — so sim_stats() is
 # always a consistent snapshot of one-or-more whole dispatches.
 _SIM_STAT_KEYS = ("dispatches", "lanes", "padded_lanes",
-                  "events_total", "events_useful")
+                  "events_total", "events_useful", "draw_columns")
 _REG = _obs_metrics.registry()
 _QN_COUNTERS = {k: _REG.counter(f"qn.{k}") for k in _SIM_STAT_KEYS}
 # Bucket-induced padding, tracked SEPARATELY from batch padding: a padded
@@ -400,8 +400,10 @@ def _count_dispatch(n: int = 1, *, lanes: int = None, padded_lanes: int = 0,
                     shard_padded_lanes: int = 0,
                     shard_padded_events: int = 0,
                     devices: int = 1,
+                    draw_columns: int = None,
                     kind: str = "mapreduce",
                     impl: str = None) -> None:
+    lanes = n if lanes is None else lanes
     with _REG.lock:
         _QN_COUNTERS["dispatches"].inc(n)
         # Labeled attribution rides beside (never instead of) the flat
@@ -410,10 +412,12 @@ def _count_dispatch(n: int = 1, *, lanes: int = None, padded_lanes: int = 0,
         _QN_COUNTERS["dispatches"].labels(
             kind=kind, impl=impl if impl is not None else default_impl(),
         ).inc(n)
-        _QN_COUNTERS["lanes"].inc(n if lanes is None else lanes)
+        _QN_COUNTERS["lanes"].inc(lanes)
         _QN_COUNTERS["padded_lanes"].inc(padded_lanes)
         _QN_COUNTERS["events_total"].inc(events_total)
         _QN_COUNTERS["events_useful"].inc(events_useful)
+        _QN_COUNTERS["draw_columns"].inc(
+            lanes if draw_columns is None else draw_columns)
         _QN_BUCKET["padded_lanes"].inc(bucket_padded_lanes)
         _QN_BUCKET["padded_events"].inc(bucket_padded_events)
         _QN_SHARD["padded_lanes"].inc(shard_padded_lanes)
@@ -457,20 +461,25 @@ def sim_stats() -> dict:
     ``lanes`` (vmapped candidate x replication programs, incl. pow2
     padding), ``padded_lanes`` (lanes that were pure padding), and the
     scan-step totals ``events_total`` vs ``events_useful`` (logical budgets
-    only) — their ratio is the batch-padding efficiency.
+    only) — their ratio is the batch-padding efficiency — and
+    ``draw_columns``, the columns of seed-only draw tables the dispatches
+    build: one per lane, or one per replication seed and shard where
+    ``response_time_batch`` passes the lanes' seed period to the
+    ``qn_event`` kernel (the pallas impl).  It is the one key that differs
+    between impls: the scan oracle draws per lane.
 
-    Backed by the ``qn.*`` counters of ``repro.obs.registry()``; the dict
-    shape and values are bit-identical to the pre-registry implementation
-    (asserted in tests/test_impl_dispatch.py)."""
+    Backed by the ``qn.*`` counters of ``repro.obs.registry()``: the dict
+    holds the registry's values, not a copy (asserted in
+    tests/test_impl_dispatch.py)."""
     with _REG.lock:
         return {k: _QN_COUNTERS[k].value for k in _SIM_STAT_KEYS}
 
 
 def reset_sim_stats() -> None:
     """Zero ALL simulator counters (dispatches, lanes, padded_lanes,
-    events_total, events_useful, and the bucket and shard padding).  This
-    is the one reset for per-run accounting; ``reset_dispatch_count`` is a
-    back-compat alias."""
+    events_total, events_useful, draw_columns, and the bucket and shard
+    padding).  This is the one reset for per-run accounting;
+    ``reset_dispatch_count`` is a back-compat alias."""
     with _REG.lock:
         for c in _QN_COUNTERS.values():
             c.reset()
@@ -645,6 +654,16 @@ def resolve_batches(batches) -> list:
     return [b._out for b in batches]
 
 
+def _check_seed_period(seeds, period: int) -> None:
+    """Raise unless lane ``l`` carries the seed of lane ``l % period``: the
+    layout under which the ``qn_event`` kernel builds its seed-only draw
+    tables for one period of lanes and broadcasts them to the rest."""
+    seeds = np.asarray(seeds)
+    if seeds.size % period or not np.array_equal(
+            seeds, np.tile(seeds[:period], seeds.size // period)):
+        raise ValueError(f"lane seeds do not repeat with period {period}")
+
+
 def response_time_batch(n_map, n_reduce, m_avg, r_avg, think_ms,
                         h_users: int, slots, min_jobs: int = 40,
                         warmup_jobs: int = 10, seed: int = 0,
@@ -691,6 +710,7 @@ def response_time_batch(n_map, n_reduce, m_avg, r_avg, think_ms,
     ``defer=True``, a ``PendingBatch`` handle that resolves to exactly
     that array without blocking the caller on the device.
     """
+    impl = default_impl() if impl is None else impl
     outer_fn, inner_fn = _batch_sim_fns(impl)
     shape = np.broadcast_shapes(*(np.shape(np.asarray(x)) for x in
                                   (n_map, n_reduce, m_avg, r_avg,
@@ -734,6 +754,9 @@ def response_time_batch(n_map, n_reduce, m_avg, r_avg, think_ms,
 
     R = replications
     seeds = seed + 1000 * np.tile(np.arange(R, dtype=np.int64), C_pad)
+    # lane c*R + r runs replication r of every candidate: its seed-only
+    # draw tables are built once per replication seed (and shard)
+    _check_seed_period(seeds, R)
     rep = lambda x: np.repeat(x, R)
 
     if m_samples is not None:
@@ -748,6 +771,10 @@ def response_time_batch(n_map, n_reduce, m_avg, r_avg, think_ms,
     # bucket would pad; pure grid rounding is whatever remains.
     shard_pad = max(C_pad - C_single, 0)
     bucket_pad = (C_pad - C) - shard_pad
+    statics = dict(h_users=int(h_users), max_slots=max_slots,
+                   n_events=scan_len, warmup_jobs=warmup_jobs)
+    if impl == "pallas":
+        statics["seed_period"] = R
     _count_dispatch(
         lanes=C_pad * R, padded_lanes=(C_pad - C) * R,
         events_total=scan_len * C_pad * R,
@@ -756,15 +783,15 @@ def response_time_batch(n_map, n_reduce, m_avg, r_avg, think_ms,
         bucket_padded_events=scan_len * bucket_pad * R,
         shard_padded_lanes=shard_pad * R,
         shard_padded_events=scan_len * shard_pad * R,
-        devices=shards, kind="mapreduce", impl=impl or default_impl())
-    statics = dict(h_users=int(h_users), max_slots=max_slots,
-                   n_events=scan_len, warmup_jobs=warmup_jobs)
+        devices=shards,
+        draw_columns=shards * R if "seed_period" in statics else None,
+        kind="mapreduce", impl=impl)
     lane_args = (
         jnp.asarray(rep(nm), jnp.int32), jnp.asarray(rep(nr), jnp.int32),
         jnp.asarray(rep(ma)), jnp.asarray(rep(ra)), jnp.asarray(rep(tk)),
         jnp.asarray(rep(sl), jnp.int32), jnp.asarray(seeds, jnp.int32),
         jnp.asarray(rep(n_ev), jnp.int32))
-    with _obs_trace.span(f"kernel:{impl or default_impl()}", cat="kernel",
+    with _obs_trace.span(f"kernel:{impl}", cat="kernel",
                          lanes=C_pad * R, candidates=C,
                          scan_len=scan_len, max_slots=max_slots,
                          replay=ms is not None, devices=shards,

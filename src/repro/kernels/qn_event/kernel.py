@@ -41,7 +41,9 @@ that tractable:
     OUTSIDE the kernel with exactly the oracle's calls (``fold_in``/
     ``exponential``/``randint`` in the same order, same fold offsets) and
     passed in as ``(n_events, lanes)`` tables; the kernel itself is
-    RNG-free.
+    RNG-free.  The service tables depend on the lane's seed alone, so
+    lanes that share a seed share them: with a ``seed_period`` they are
+    built once per seed and broadcast to the lanes (``qn_event_fwd``).
   * The draw-consuming arithmetic (``now + e*mean``, ``t_slot +
     e*think``) keeps the oracle's exact op structure IN-KERNEL — XLA
     contracts ``add(x, mul(a, b))`` chains into FMAs inside loop bodies,
@@ -78,28 +80,26 @@ EVENT_CHUNK = 1024      # events per block of the streamed draw tables
 # RNG streams — bit-identical to the oracle's in-scan draws
 # ---------------------------------------------------------------------------
 
-def event_streams(m_avg, r_avg, think_ms, seed, n_events_active, *,
-                  h_users: int, n_events: int,
-                  m_samples=None, r_samples=None):
-    """Per-lane random tables: initial think clocks ``(H,)`` plus per-event
-    service and think draws ``(E,)``.
+def seed_streams(seed, *, h_users: int, n_events: int,
+                 m_samples=None, r_samples=None):
+    """One seed's draws: unit initial think clocks ``(H,)`` plus the
+    per-event service draws ``(E,)`` (map and reduce).  They depend on the
+    seed (and the shared sample lists) alone, never on a lane's budget,
+    slots or state, so lanes that share a seed share these tables.
 
-    Must mirror ``qn_sim._init_state`` / ``qn_sim._make_step`` exactly:
-      * init:     ``k0, _ = split(key);  exponential(k0, (H,)) * think_ms``
-        (outside the oracle's scan, so the multiply is safe out here);
+    Must mirror ``qn_sim._init_state`` / ``qn_sim._rng_tables`` exactly:
+      * init:     ``k0, _ = split(key);  exponential(k0, (H,))`` — the
+        oracle's ``* think_ms`` is applied per lane by the caller (outside
+        the oracle's scan, so the multiply is safe out there);
       * event i:  ``key_i = fold_in(key, i)`` drives ONE unit exponential
         — returned UNSCALED (the ``e * mean`` multiply must stay in-kernel
         next to its consuming add, see module docstring) — or, in replay
         mode, two ``randint`` index draws into the shared sample lists
-        (replay values are used verbatim: no multiply to preserve);
-      * think:    ``kq = fold_in(key, i + n_events_active)``, also unit
-        (the logical budget is the fold offset — that is what makes a
-        padded lane reproduce its scalar run).
+        (replay values are used verbatim: no multiply to preserve).
     """
     key = jax.random.key(seed)
     k0, _ = jax.random.split(key)
-    think0 = jax.random.exponential(k0, (h_users,)) * think_ms
-    idx = jnp.arange(n_events)
+    think0 = jax.random.exponential(k0, (h_users,))
 
     def service(i):
         key_i = jax.random.fold_in(key, i)
@@ -110,12 +110,32 @@ def event_streams(m_avg, r_avg, think_ms, seed, n_events_active, *,
         e = jax.random.exponential(key_i)
         return e, e
 
+    st_m, st_r = jax.vmap(service)(jnp.arange(n_events))
+    return think0, st_m, st_r
+
+
+def think_stream(seed, n_events_active, *, n_events: int):
+    """One lane's unit think redraws ``(E,)``: ``kq = fold_in(key, i +
+    n_events_active)`` (the logical budget is the fold offset — that is
+    what makes a padded lane reproduce its scalar run), so this table stays
+    per lane."""
+    key = jax.random.key(seed)
+
     def think(i):
         kq = jax.random.fold_in(key, i + n_events_active)
         return jax.random.exponential(kq)
 
-    st_m, st_r = jax.vmap(service)(idx)
-    return think0, st_m, st_r, jax.vmap(think)(idx)
+    return jax.vmap(think)(jnp.arange(n_events))
+
+
+def _tile_lanes(table, lanes: int):
+    """``(rows, P)`` -> ``(rows, lanes)``, column ``l`` holding column
+    ``l % P``: a broadcast and a reshape, so the expansion stays a memory
+    pass (no gather)."""
+    rows, period = table.shape
+    return jnp.broadcast_to(table[:, None, :],
+                            (rows, lanes // period, period)) \
+        .reshape(rows, lanes)
 
 
 # ---------------------------------------------------------------------------
@@ -300,25 +320,40 @@ def _event_kernel(ip_ref, fp_ref, think0_ref, stm_ref, str_ref, td_ref,
 def qn_event_fwd(n_map, n_reduce, m_avg, r_avg, think_ms, slots_cap, seed,
                  n_events_active, m_samples=None, r_samples=None, *,
                  h_users: int, max_slots: int, n_events: int,
-                 warmup_jobs: int, interpret: bool):
+                 warmup_jobs: int, interpret: bool, seed_period: int = None):
     """Drop-in for ``qn_sim._sim_batch_jit``: all per-lane parameters are
     ``(B,)`` arrays, replay sample lists (when given) are shared across the
     batch.  Returns ``(mean_resp, resp_cnt)`` per lane, bit-identical (in
-    interpret mode) to the ``lax.scan`` oracle."""
+    interpret mode) to the ``lax.scan`` oracle.
+
+    ``seed_period``: the caller's guarantee that lane ``l`` carries the
+    seed of lane ``l % seed_period`` (``qn_sim.response_time_batch`` gives
+    lane ``c*R + r`` the seed ``seed + 1000*r`` and checks it).  The
+    seed-only tables (``seed_streams``) are then built for the first
+    ``seed_period`` lanes and broadcast to the rest: the same values for
+    ``seed_period / B`` of the draws.  ``None`` builds them per lane (a
+    period of ``B``, for which the broadcast is the identity)."""
     B = n_map.shape[0]
+    if seed_period is not None and B % seed_period:
+        raise ValueError(f"{B} lanes are not a whole number of seed "
+                         f"periods of {seed_period}")
     L = min(B, LANE_TILE)
     lane_pad = (-B) % L
     chunk = min(EVENT_CHUNK, -(-n_events // 8) * 8)
     event_pad = (-n_events) % chunk
 
-    streams = functools.partial(event_streams, h_users=h_users,
+    streams = functools.partial(seed_streams, h_users=h_users,
                                 n_events=n_events, m_samples=m_samples,
                                 r_samples=r_samples)
     # the draw tables are built by XLA outside the kernel; the scope names
     # their device ops apart from the kernel's in a profile
     with jax.named_scope("qn_event_draws"):
-        think0, st_m, st_r, td = jax.vmap(streams, out_axes=1)(
-            m_avg, r_avg, think_ms, seed, n_events_active)
+        P = B if seed_period is None else seed_period
+        think0, st_m, st_r = (_tile_lanes(t, B) for t in
+                              jax.vmap(streams, out_axes=1)(seed[:P]))
+        think0 = think0 * think_ms       # the oracle's init multiply
+        td = jax.vmap(functools.partial(think_stream, n_events=n_events),
+                      out_axes=1)(seed, n_events_active)
 
     ip = jnp.stack([n_map, n_reduce, slots_cap, n_events_active]) \
         .astype(jnp.int32)

@@ -11,6 +11,7 @@ module is imported — because only one process at a time may load the TPU
 library; every test of that need lives in this one file.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -93,6 +94,25 @@ def test_qn_event_compiles_at_524288_events(one_chip, lanes, max_slots,
     compiled = jax.jit(fwd).lower(*_lanes(lanes, one_chip),
                                   *samples).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    _fits_hbm(compiled)
+
+
+def test_qn_event_builds_replay_draws_once_per_seed(one_chip):
+    """Twelve lanes of two seeds (6 candidates x 2 replications): the
+    compiled program gathers the replay samples for the two seeds only,
+    and broadcasts them to the lanes without a gather of its own."""
+    lanes, period = 12, 2
+    s = jax.ShapeDtypeStruct((2048,), jnp.float32, sharding=one_chip)
+    fwd = functools.partial(
+        qn_kernel.qn_event_fwd, h_users=1, max_slots=128, n_events=EVENTS,
+        warmup_jobs=8, interpret=False, seed_period=period)
+    compiled = jax.jit(fwd).lower(*_lanes(lanes, one_chip), s, s).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    gathered = [int(np.prod([int(d) for d in dims.split(",")]))
+                for dims in re.findall(r"= \w+\[([\d,]+)\]\S* gather\(",
+                                       text)]
+    assert gathered and max(gathered) == period * EVENTS, gathered
     _fits_hbm(compiled)
 
 

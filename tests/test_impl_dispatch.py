@@ -7,7 +7,9 @@ inside ``run``) are driven end to end under ``impl="jnp"`` and
 ``sim_stats()`` accounting — dispatches, lanes, padding, event totals are
 counted at the marshaling layer, before the backend dispatch, so a kernel
 swap can never silently alter the optimizer's search path or its dispatch
-budget."""
+budget.  The one exception is ``draw_columns``, the draw-table columns a
+backend builds: one per lane under the scan, fewer under the kernel, which
+builds its seed-only tables once per replication seed."""
 import jax
 import pytest
 
@@ -68,7 +70,10 @@ def _assert_equivalent(make_report):
     rep_j, stats_j = _with_impl("jnp", make_report)
     rep_p, stats_p = _with_impl("pallas", make_report)
     assert stats_j["dispatches"] > 0
+    cols_j, cols_p = stats_j.pop("draw_columns"), stats_p.pop("draw_columns")
     assert stats_j == stats_p                    # identical accounting
+    assert cols_j == stats_j["lanes"]            # the scan draws per lane
+    assert stats_j["dispatches"] <= cols_p < cols_j
     assert rep_j.solutions == rep_p.solutions    # bit-identical search result
     assert rep_j.total_cost_per_h == rep_p.total_cost_per_h
     return rep_j

@@ -186,7 +186,7 @@ for grid in shapes.GRIDS:
             replications=2, impl=impl)
         base_r = qn_sim.response_time_batch(
             16, 4, 0.0, 0.0, 8000.0, 3, SLOTS[:3], min_jobs=5,
-            replications=1, m_samples=ms, r_samples=rs, impl=impl)
+            replications=2, m_samples=ms, r_samples=rs, impl=impl)
         for D in (1, 2, 4):
             partition.set_shard_spec(D)
             d0 = qn_sim.dispatch_count()
@@ -195,10 +195,15 @@ for grid in shapes.GRIDS:
                 replications=2, impl=impl)
             assert qn_sim.dispatch_count() - d0 == 1   # still ONE dispatch
             assert np.array_equal(base, got), (grid, impl, D)
+            s0 = qn_sim.sim_stats()
             got_r = qn_sim.response_time_batch(
                 16, 4, 0.0, 0.0, 8000.0, 3, SLOTS[:3], min_jobs=5,
-                replications=1, m_samples=ms, r_samples=rs, impl=impl)
+                replications=2, m_samples=ms, r_samples=rs, impl=impl)
             assert np.array_equal(base_r, got_r), (grid, impl, D, "replay")
+            s1 = qn_sim.sim_stats()
+            # each shard builds its seed-only tables for the 2 seeds
+            assert s1["draw_columns"] - s0["draw_columns"] == (
+                2 * D if impl == "pallas" else s1["lanes"] - s0["lanes"])
     partition.set_shard_spec("off")
     dbase = dag_mod.response_time_batch([job] * 5, 8000.0, SLOTS[:5], 3,
                                         min_jobs=5, replications=2)

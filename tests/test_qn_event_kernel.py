@@ -17,7 +17,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.core import qn_sim
+from repro.core import partition, qn_sim, shapes
 from repro.kernels.qn_event import kernel as qn_kernel
 from repro.kernels.qn_event import ops as qn_event_ops
 from repro.kernels.qn_event import ref as qn_event_ref
@@ -63,10 +63,16 @@ def test_parity_task_counts(n_map, n_reduce):
     assert np.array_equal(a, b)
 
 
-def test_parity_replay_mode():
-    ms = [30.0, 45.0, 55.0, 38.0, 61.0]
-    rs = [80.0, 95.0, 70.0]
-    a, b = _pair([3, 6, 12], 2, m_samples=ms, r_samples=rs)
+REPLAY = dict(m_samples=[30.0, 45.0, 55.0, 38.0, 61.0],
+              r_samples=[80.0, 95.0, 70.0])
+
+
+# the pallas path builds the replay tables once per replication seed and
+# broadcasts them to the candidates; 3 and 5 candidates pad the lane axis
+@pytest.mark.parametrize("replications", [1, 2, 3])
+@pytest.mark.parametrize("slots", [[3, 6, 12], [2, 4, 5, 9, 13]])
+def test_parity_replay_mode(slots, replications):
+    a, b = _pair(slots, 2, replications=replications, **REPLAY)
     assert np.array_equal(a, b)
 
 
@@ -76,18 +82,24 @@ def test_parity_across_seeds_and_replications():
         assert np.array_equal(a, b), seed
 
 
-def _direct_args(budgets, slots, seed=0):
+def _direct_args(budgets, slots, seed=0, seed_period=None, samples=None):
     """Hand-built fused-batch arguments with per-lane budgets (including
-    zero = pure-padding lanes)."""
+    zero = pure-padding lanes); every lane has its own seed, or lane ``l``
+    the seed of lane ``l % seed_period``."""
     B = len(budgets)
     n_events = max(budgets)
     full = lambda v, dt: jnp.full((B,), v, dt)
+    lane = np.arange(B) if seed_period is None \
+        else np.arange(B) % seed_period
+    shared = (None, None) if samples is None else \
+        tuple(jnp.asarray(samples[k], jnp.float32)
+              for k in ("m_samples", "r_samples"))
     args = (full(BASE["n_map"], jnp.int32), full(BASE["n_reduce"], jnp.int32),
             full(BASE["m_avg"], jnp.float32), full(BASE["r_avg"], jnp.float32),
             full(BASE["think_ms"], jnp.float32),
             jnp.asarray(slots, jnp.int32),
-            jnp.asarray(seed + 1000 * np.arange(B), jnp.int32),
-            jnp.asarray(budgets, jnp.int32), None, None)
+            jnp.asarray(seed + 1000 * lane, jnp.int32),
+            jnp.asarray(budgets, jnp.int32)) + shared
     statics = dict(h_users=3, max_slots=int(max(slots)),
                    n_events=n_events, warmup_jobs=2)
     return args, statics
@@ -128,6 +140,77 @@ def test_streamed_event_blocks_and_lane_blocks_bitwise(monkeypatch):
     assert np.array_equal(np.asarray(cnt_k), np.asarray(cnt_o))
     assert np.array_equal(np.asarray(mean_k), np.asarray(mean_o))
     assert float(cnt_k[2]) == 0.0 and float(cnt_k[0]) > 0.0
+
+
+@pytest.mark.parametrize("samples", [None, REPLAY], ids=["exp", "replay"])
+@pytest.mark.parametrize("seed_period", [2, 3])
+def test_seed_period_tables_bitwise_equal_per_lane_tables(
+        monkeypatch, seed_period, samples):
+    """Tables built once per seed and broadcast to the lanes give the same
+    output as tables built per lane, and as the oracle — across several
+    lane blocks and streamed event blocks, each with a padded tail."""
+    monkeypatch.setattr(qn_kernel, "LANE_TILE", 8)
+    monkeypatch.setattr(qn_kernel, "EVENT_CHUNK", 24)
+    budget = qn_sim.padded_event_budget(BASE["n_map"], BASE["n_reduce"],
+                                        min_jobs=8, warmup_jobs=2)
+    budgets = [budget, budget // 3, 0, budget // 2, budget, budget // 5] * 3
+    slots = [1 + i % 5 for i in range(len(budgets))]       # 18 lanes
+    args, statics = _direct_args(budgets, slots, seed=11,
+                                 seed_period=seed_period, samples=samples)
+    fwd = functools.partial(qn_kernel.qn_event_fwd, **statics,
+                            interpret=True)
+    mean_p, cnt_p = jax.jit(functools.partial(
+        fwd, seed_period=seed_period))(*args)
+    mean_l, cnt_l = jax.jit(fwd)(*args)
+    mean_o, cnt_o = qn_event_ref.sim_batch(*args, **statics)
+    assert np.array_equal(np.asarray(cnt_p), np.asarray(cnt_l))
+    assert np.array_equal(np.asarray(mean_p), np.asarray(mean_l))
+    assert np.array_equal(np.asarray(mean_p), np.asarray(mean_o))
+    assert float(cnt_p[0]) > 0.0 and float(cnt_p[2]) == 0.0
+
+
+@pytest.mark.parametrize("seeds,period", [
+    ([0, 1000, 0, 1001], 2),       # a later lane breaks the period
+    ([0, 1000, 0], 2),             # not a whole number of periods
+    ([5, 5, 5, 6], 1),
+])
+def test_seed_period_check_raises_on_a_broken_period(seeds, period):
+    with pytest.raises(ValueError, match="period"):
+        qn_sim._check_seed_period(np.asarray(seeds), period)
+    qn_sim._check_seed_period(np.tile(np.asarray(seeds[:period]), 3),
+                              period)
+
+
+def test_kernel_refuses_lanes_that_are_not_whole_seed_periods():
+    args, statics = _direct_args([16] * 5, [2] * 5)
+    with pytest.raises(ValueError, match="seed periods of 2"):
+        qn_kernel.qn_event_fwd(*args, **statics, interpret=True,
+                               seed_period=2)
+
+
+@pytest.mark.parametrize("replications", [1, 2, 3])
+def test_draw_columns_count_one_per_seed_with_the_period(replications):
+    """A batched dispatch builds its seed-only tables once per replication
+    seed; a scalar dispatch (no period) once per lane."""
+    old = partition.shard_spec()
+    partition.set_shard_spec("off")
+    try:
+        s0 = qn_sim.sim_stats()
+        kw = {**BASE, **FAST, "replications": replications, "h_users": 2}
+        qn_sim.response_time_batch(**kw, slots=[2, 3, 5, 7, 9],
+                                   impl="pallas", **REPLAY)
+        s1 = qn_sim.sim_stats()
+        qn_sim.response_time(**kw, slots=3)
+        s2 = qn_sim.sim_stats()
+    finally:
+        partition.set_shard_spec(old)
+    assert s1["dispatches"] - s0["dispatches"] == 1
+    assert s1["draw_columns"] - s0["draw_columns"] == replications
+    assert s1["lanes"] - s0["lanes"] \
+        == shapes.bucket_lanes(5) * replications
+    assert s2["dispatches"] - s1["dispatches"] == replications
+    assert s2["draw_columns"] - s1["draw_columns"] \
+        == s2["lanes"] - s1["lanes"] == replications
 
 
 def test_single_slot_single_user_degenerate():
