@@ -369,7 +369,8 @@ def _batch_sim_fns(impl):
 # same guarantee the old private _DISPATCH_LOCK gave — so sim_stats() is
 # always a consistent snapshot of one-or-more whole dispatches.
 _SIM_STAT_KEYS = ("dispatches", "lanes", "padded_lanes",
-                  "events_total", "events_useful", "draw_columns")
+                  "events_total", "events_useful", "draw_columns",
+                  "replay_draws", "replay_lookups")
 _REG = _obs_metrics.registry()
 _QN_COUNTERS = {k: _REG.counter(f"qn.{k}") for k in _SIM_STAT_KEYS}
 # Bucket-induced padding, tracked SEPARATELY from batch padding: a padded
@@ -401,6 +402,8 @@ def _count_dispatch(n: int = 1, *, lanes: int = None, padded_lanes: int = 0,
                     shard_padded_events: int = 0,
                     devices: int = 1,
                     draw_columns: int = None,
+                    replay_draws: int = 0,
+                    replay_lookups: int = 0,
                     kind: str = "mapreduce",
                     impl: str = None) -> None:
     lanes = n if lanes is None else lanes
@@ -418,6 +421,8 @@ def _count_dispatch(n: int = 1, *, lanes: int = None, padded_lanes: int = 0,
         _QN_COUNTERS["events_useful"].inc(events_useful)
         _QN_COUNTERS["draw_columns"].inc(
             lanes if draw_columns is None else draw_columns)
+        _QN_COUNTERS["replay_draws"].inc(replay_draws)
+        _QN_COUNTERS["replay_lookups"].inc(replay_lookups)
         _QN_BUCKET["padded_lanes"].inc(bucket_padded_lanes)
         _QN_BUCKET["padded_events"].inc(bucket_padded_events)
         _QN_SHARD["padded_lanes"].inc(shard_padded_lanes)
@@ -465,8 +470,12 @@ def sim_stats() -> dict:
     ``draw_columns``, the columns of seed-only draw tables the dispatches
     build: one per lane, or one per replication seed and shard where
     ``response_time_batch`` passes the lanes' seed period to the
-    ``qn_event`` kernel (the pallas impl).  It is the one key that differs
-    between impls: the scan oracle draws per lane.
+    ``qn_event`` kernel (the pallas impl).  ``replay_draws`` counts the
+    replay service draws the dispatches need (map and reduce: 2 per draw
+    column and scan step) and ``replay_lookups`` those the ``qn_event``
+    kernel's lookup (``qn_replay_lookup``) copies out of the sample lists.
+    ``draw_columns`` and ``replay_lookups`` are the keys that differ
+    between impls: the scan oracle draws per lane and gathers in XLA.
 
     Backed by the ``qn.*`` counters of ``repro.obs.registry()``: the dict
     holds the registry's values, not a copy (asserted in
@@ -477,9 +486,10 @@ def sim_stats() -> dict:
 
 def reset_sim_stats() -> None:
     """Zero ALL simulator counters (dispatches, lanes, padded_lanes,
-    events_total, events_useful, draw_columns, and the bucket and shard
-    padding).  This is the one reset for per-run accounting;
-    ``reset_dispatch_count`` is a back-compat alias."""
+    events_total, events_useful, draw_columns, replay_draws,
+    replay_lookups, and the bucket and shard padding).  This is the one
+    reset for per-run accounting; ``reset_dispatch_count`` is a
+    back-compat alias."""
     with _REG.lock:
         for c in _QN_COUNTERS.values():
             c.reset()
@@ -575,7 +585,8 @@ def response_time(n_map: int, n_reduce: int, m_avg: float, r_avg: float,
     outs, cnts = [], []
     for r in range(replications):
         ne = _shapes.bucket_events(p.n_events)
-        _count_dispatch(events_total=ne, events_useful=ne, impl="jnp")
+        _count_dispatch(events_total=ne, events_useful=ne,
+                        replay_draws=2 * ne, impl="jnp")
         with _obs_trace.span("kernel:scalar", cat="kernel", events=ne,
                              replay=True):
             m, c = _sim_replay_jit(
@@ -775,6 +786,8 @@ def response_time_batch(n_map, n_reduce, m_avg, r_avg, think_ms,
                    n_events=scan_len, warmup_jobs=warmup_jobs)
     if impl == "pallas":
         statics["seed_period"] = R
+    columns = shards * R if "seed_period" in statics else C_pad * R
+    replay_draws = 2 * columns * scan_len if ms is not None else 0
     _count_dispatch(
         lanes=C_pad * R, padded_lanes=(C_pad - C) * R,
         events_total=scan_len * C_pad * R,
@@ -784,7 +797,10 @@ def response_time_batch(n_map, n_reduce, m_avg, r_avg, think_ms,
         shard_padded_lanes=shard_pad * R,
         shard_padded_events=scan_len * shard_pad * R,
         devices=shards,
-        draw_columns=shards * R if "seed_period" in statics else None,
+        draw_columns=columns, replay_draws=replay_draws,
+        # the kernel copies every replay draw out of the lists in
+        # qn_replay_lookup; the scan oracle gathers them in XLA
+        replay_lookups=replay_draws if impl == "pallas" else 0,
         kind="mapreduce", impl=impl)
     lane_args = (
         jnp.asarray(rep(nm), jnp.int32), jnp.asarray(rep(nr), jnp.int32),

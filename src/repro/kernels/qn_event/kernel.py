@@ -37,11 +37,13 @@ that tractable:
   * Every random draw of the oracle is a pure function of ``(key, i)`` —
     the event index — never of simulation state (the *mean* is selected by
     state, the unit-exponential draw is not).  So the streams (unit
-    service/think exponentials, or replay sample gathers) are precomputed
+    service/think exponentials, or replay samples) are precomputed
     OUTSIDE the kernel with exactly the oracle's calls (``fold_in``/
     ``exponential``/``randint`` in the same order, same fold offsets) and
     passed in as ``(n_events, lanes)`` tables; the kernel itself is
-    RNG-free.  The service tables depend on the lane's seed alone, so
+    RNG-free.  Replay samples are copied out of the lists at the oracle's
+    indices by a second, small kernel (``replay_lookup``), never
+    computed.  The service tables depend on the lane's seed alone, so
     lanes that share a seed share them: with a ``seed_period`` they are
     built once per seed and broadcast to the lanes (``qn_event_fwd``).
   * The draw-consuming arithmetic (``now + e*mean``, ``t_slot +
@@ -74,18 +76,18 @@ from jax.experimental.pallas import tpu as pltpu
 INF = 1e30
 LANE_TILE = 128         # lanes per grid block: the TPU's minor tile
 EVENT_CHUNK = 1024      # events per block of the streamed draw tables
+LOOKUP_ROWS = 256       # 128-wide index rows per block of the replay lookup
 
 
 # ---------------------------------------------------------------------------
 # RNG streams — bit-identical to the oracle's in-scan draws
 # ---------------------------------------------------------------------------
 
-def seed_streams(seed, *, h_users: int, n_events: int,
-                 m_samples=None, r_samples=None):
+def seed_streams(seed, *, h_users: int, n_events: int, list_lens=None):
     """One seed's draws: unit initial think clocks ``(H,)`` plus the
-    per-event service draws ``(E,)`` (map and reduce).  They depend on the
-    seed (and the shared sample lists) alone, never on a lane's budget,
-    slots or state, so lanes that share a seed share these tables.
+    per-event service draws ``(E,)``.  They depend on the seed alone, never
+    on a lane's budget, slots or state, so lanes that share a seed share
+    these tables.
 
     Must mirror ``qn_sim._init_state`` / ``qn_sim._rng_tables`` exactly:
       * init:     ``k0, _ = split(key);  exponential(k0, (H,))`` — the
@@ -94,8 +96,12 @@ def seed_streams(seed, *, h_users: int, n_events: int,
       * event i:  ``key_i = fold_in(key, i)`` drives ONE unit exponential
         — returned UNSCALED (the ``e * mean`` multiply must stay in-kernel
         next to its consuming add, see module docstring) — or, in replay
-        mode, two ``randint`` index draws into the shared sample lists
-        (replay values are used verbatim: no multiply to preserve).
+        mode (``list_lens`` given), the oracle's ``randint(key_i, (), 0,
+        n)`` index into a sample list of each length ``n`` in
+        ``list_lens``: a tuple of ``(E,)`` int32 tables, whose entries
+        ``replay_lookup`` copies out of the lists.  The oracle draws the
+        map and the reduce index with the same key, so lists of equal
+        length share one index table.
     """
     key = jax.random.key(seed)
     k0, _ = jax.random.split(key)
@@ -103,15 +109,63 @@ def seed_streams(seed, *, h_users: int, n_events: int,
 
     def service(i):
         key_i = jax.random.fold_in(key, i)
-        if m_samples is not None:
-            idx_m = jax.random.randint(key_i, (), 0, m_samples.shape[0])
-            idx_r = jax.random.randint(key_i, (), 0, r_samples.shape[0])
-            return m_samples[idx_m], r_samples[idx_r]
-        e = jax.random.exponential(key_i)
-        return e, e
+        if list_lens is not None:
+            return tuple(jax.random.randint(key_i, (), 0, n)
+                         for n in list_lens)
+        return jax.random.exponential(key_i)
 
-    st_m, st_r = jax.vmap(service)(jnp.arange(n_events))
-    return think0, st_m, st_r
+    return think0, jax.vmap(service)(jnp.arange(n_events))
+
+
+def _copy_entries(idx, rows_ref):
+    """``list[idx]`` for a list held as ``(rows, 128)`` lane rows: for each
+    row, an in-vreg lane gather of every index's low 7 bits, kept where
+    the high bits name that row.  Each value is a copy of a list entry."""
+    hi, lo = idx >> 7, idx & 127
+
+    def row(h, out):
+        vals = jnp.broadcast_to(rows_ref[pl.ds(h, 1), :], idx.shape)
+        got = jnp.take_along_axis(vals, lo, axis=1,
+                                  mode="promise_in_bounds")
+        return jnp.where(hi == h, got, out)
+
+    return jax.lax.fori_loop(0, rows_ref.shape[0], row,
+                             jnp.zeros(idx.shape, rows_ref.dtype))
+
+
+def _lookup_kernel(*refs):
+    *idx_refs, m_ref, r_ref, stm_ref, str_ref = refs
+    stm_ref[...] = _copy_entries(idx_refs[0][...], m_ref)
+    str_ref[...] = _copy_entries(idx_refs[-1][...], r_ref)
+
+
+def replay_lookup(idx, m_samples, r_samples, *, interpret: bool):
+    """``(m_samples[idx_m], r_samples[idx_r])``, each shaped like the index
+    tables, as a Pallas kernel (``qn_replay_lookup``) that holds both lists
+    in VMEM.  ``idx`` is ``(idx_m, idx_r)``, or ``(idx,)`` when both lists
+    share it.  The tables are flattened into 128-wide rows and padded with
+    index 0 to whole blocks of ``LOOKUP_ROWS`` rows."""
+    shape, n = idx[0].shape, idx[0].size
+    block = LOOKUP_ROWS * 128
+    rows = [jnp.pad(i.reshape(-1), (0, -n % block)).reshape(-1, 128)
+            for i in idx]
+    lists = [jnp.pad(s, (0, -s.shape[0] % 128)).reshape(-1, 128)
+             for s in (m_samples, r_samples)]
+    grid = rows[0].shape[0] // LOOKUP_ROWS
+    blocks = pl.BlockSpec((LOOKUP_ROWS, 128), lambda b: (b, 0))
+    whole = [pl.BlockSpec(x.shape, lambda b: (0, 0)) for x in lists]
+    out = pl.pallas_call(
+        _lookup_kernel,
+        grid=(grid,),
+        in_specs=[blocks] * len(rows) + whole,
+        out_specs=[blocks, blocks],
+        out_shape=[jax.ShapeDtypeStruct(rows[0].shape, jnp.float32)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="qn_replay_lookup",
+    )(*rows, *lists)
+    return tuple(t.reshape(-1)[:n].reshape(shape) for t in out)
 
 
 def think_stream(seed, n_events_active, *, n_events: int):
@@ -342,15 +396,22 @@ def qn_event_fwd(n_map, n_reduce, m_avg, r_avg, think_ms, slots_cap, seed,
     chunk = min(EVENT_CHUNK, -(-n_events // 8) * 8)
     event_pad = (-n_events) % chunk
 
+    replay = m_samples is not None
+    # one index table per distinct list length (static shapes)
+    list_lens = tuple(dict.fromkeys(
+        (m_samples.shape[0], r_samples.shape[0]))) if replay else None
     streams = functools.partial(seed_streams, h_users=h_users,
-                                n_events=n_events, m_samples=m_samples,
-                                r_samples=r_samples)
-    # the draw tables are built by XLA outside the kernel; the scope names
+                                n_events=n_events, list_lens=list_lens)
+    # the draw tables are built outside the event kernel; the scope names
     # their device ops apart from the kernel's in a profile
     with jax.named_scope("qn_event_draws"):
         P = B if seed_period is None else seed_period
-        think0, st_m, st_r = (_tile_lanes(t, B) for t in
-                              jax.vmap(streams, out_axes=1)(seed[:P]))
+        think0, draws = jax.vmap(streams)(seed[:P])
+        st_m, st_r = replay_lookup(draws, m_samples, r_samples,
+                                   interpret=interpret) if replay \
+            else (draws, draws)
+        think0, st_m, st_r = (_tile_lanes(t.T, B)
+                              for t in (think0, st_m, st_r))
         think0 = think0 * think_ms       # the oracle's init multiply
         td = jax.vmap(functools.partial(think_stream, n_events=n_events),
                       out_axes=1)(seed, n_events_active)
@@ -373,7 +434,7 @@ def qn_event_fwd(n_map, n_reduce, m_avg, r_avg, think_ms, slots_cap, seed,
     layout = _state_layout(L, max_slots, h_users)
     kernel = functools.partial(
         _event_kernel, layout=layout, max_slots=max_slots, chunk=chunk,
-        warmup_jobs=warmup_jobs, replay=m_samples is not None)
+        warmup_jobs=warmup_jobs, replay=replay)
     rows = lambda n: pl.BlockSpec((n, L), lambda b, c: (0, b))
     table = pl.BlockSpec((chunk, L), lambda b, c: (c, b))
     out = pl.pallas_call(

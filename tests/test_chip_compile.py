@@ -99,8 +99,10 @@ def test_qn_event_compiles_at_524288_events(one_chip, lanes, max_slots,
 
 def test_qn_event_builds_replay_draws_once_per_seed(one_chip):
     """Twelve lanes of two seeds (6 candidates x 2 replications): the
-    compiled program gathers the replay samples for the two seeds only,
-    and broadcasts them to the lanes without a gather of its own."""
+    compiled program draws one replay index table for the two seeds only
+    (both lists have 2,048 entries, so they share it), copies the samples
+    out of the lists in the ``qn_replay_lookup`` kernel, and broadcasts
+    them to the lanes: no gather anywhere."""
     lanes, period = 12, 2
     s = jax.ShapeDtypeStruct((2048,), jnp.float32, sharding=one_chip)
     fwd = functools.partial(
@@ -109,10 +111,15 @@ def test_qn_event_builds_replay_draws_once_per_seed(one_chip):
     compiled = jax.jit(fwd).lower(*_lanes(lanes, one_chip), s, s).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
-    gathered = [int(np.prod([int(d) for d in dims.split(",")]))
-                for dims in re.findall(r"= \w+\[([\d,]+)\]\S* gather\(",
-                                       text)]
-    assert gathered and max(gathered) == period * EVENTS, gathered
+    assert "gather(" not in text
+    lookup = [ln for ln in text.splitlines()
+              if re.search(r"%qn_replay_lookup\S* = .* custom-call\(", ln)]
+    assert len(lookup) == 1, lookup
+    operands = re.search(r"operand_layout_constraints=\{(.*?\})\}",
+                         lookup[0]).group(1)
+    tables = [int(np.prod([int(d) for d in dims.split(",")]))
+              for dims in re.findall(r"s32\[([\d,]+)\]", operands)]
+    assert tables == [period * EVENTS], operands
     _fits_hbm(compiled)
 
 

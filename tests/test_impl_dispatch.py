@@ -7,9 +7,11 @@ inside ``run``) are driven end to end under ``impl="jnp"`` and
 ``sim_stats()`` accounting — dispatches, lanes, padding, event totals are
 counted at the marshaling layer, before the backend dispatch, so a kernel
 swap can never silently alter the optimizer's search path or its dispatch
-budget.  The one exception is ``draw_columns``, the draw-table columns a
+budget.  The exceptions are ``draw_columns``, the draw-table columns a
 backend builds: one per lane under the scan, fewer under the kernel, which
-builds its seed-only tables once per replication seed."""
+builds its seed-only tables once per replication seed; and
+``replay_lookups``, the replay draws the kernel's lookup copies out of the
+sample lists: none under the scan, every one under the kernel."""
 import jax
 import pytest
 
@@ -71,9 +73,13 @@ def _assert_equivalent(make_report):
     rep_p, stats_p = _with_impl("pallas", make_report)
     assert stats_j["dispatches"] > 0
     cols_j, cols_p = stats_j.pop("draw_columns"), stats_p.pop("draw_columns")
+    look_j = stats_j.pop("replay_lookups")
+    look_p = stats_p.pop("replay_lookups")
     assert stats_j == stats_p                    # identical accounting
     assert cols_j == stats_j["lanes"]            # the scan draws per lane
     assert stats_j["dispatches"] <= cols_p < cols_j
+    assert look_j == 0                           # the scan gathers in XLA
+    assert look_p == stats_p["replay_draws"]     # the kernel copies each
     assert rep_j.solutions == rep_p.solutions    # bit-identical search result
     assert rep_j.total_cost_per_h == rep_p.total_cost_per_h
     return rep_j

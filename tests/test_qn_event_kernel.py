@@ -169,6 +169,59 @@ def test_seed_period_tables_bitwise_equal_per_lane_tables(
     assert float(cnt_p[0]) > 0.0 and float(cnt_p[2]) == 0.0
 
 
+def _bits(x):
+    return np.asarray(x, np.float32).view(np.uint32)
+
+
+# Table-3 lists are capped at 2,048 entries; Q5 1000 GB has 1,280 and 1,360
+@pytest.mark.parametrize("seeds", [1, 3])
+@pytest.mark.parametrize("lens", [(2048, 2048), (1280, 1360)])
+def test_replay_lookup_copies_the_oracles_draws_bitwise(monkeypatch, lens,
+                                                        seeds):
+    """The lookup kernel returns ``list[idx]`` bit for bit, and with the
+    index tables of ``seed_streams`` those are the oracle's own replay
+    draws; 700 events a seed fill no whole block of 1,024 draws."""
+    monkeypatch.setattr(qn_kernel, "LOOKUP_ROWS", 8)
+    n_events = 700
+    rng = np.random.default_rng(sum(lens) + seeds)
+    m, r = (jnp.asarray(rng.uniform(1.0, 1e5, n), jnp.float32)
+            for n in lens)
+    seed = jnp.asarray(11 + 1000 * np.arange(seeds), jnp.int32)
+    streams = functools.partial(qn_kernel.seed_streams, h_users=1,
+                                n_events=n_events,
+                                list_lens=tuple(dict.fromkeys(lens)))
+    _, idx = jax.vmap(streams)(seed)
+    st_m, st_r = qn_kernel.replay_lookup(idx, m, r, interpret=True)
+    assert st_m.shape == st_r.shape == (seeds, n_events)
+    assert np.array_equal(_bits(st_m), _bits(m)[np.asarray(idx[0])])
+    assert np.array_equal(_bits(st_r), _bits(r)[np.asarray(idx[-1])])
+    for s in range(seeds):
+        om, orr, _ = qn_sim._rng_tables(jax.random.key(int(seed[s])),
+                                        n_events, 0, m, r)
+        assert np.array_equal(_bits(st_m[s]), _bits(om))
+        assert np.array_equal(_bits(st_r[s]), _bits(orr))
+
+
+@pytest.mark.parametrize("lens,tables", [((2048, 2048), 1),
+                                         ((1280, 1360), 2)])
+def test_equal_list_lengths_share_one_index_table(monkeypatch, lens, tables):
+    seen = []
+    lookup = qn_kernel.replay_lookup
+
+    def spy(idx, *args, **kw):
+        seen.append(len(idx))
+        return lookup(idx, *args, **kw)
+
+    monkeypatch.setattr(qn_kernel, "replay_lookup", spy)
+    samples = {k: np.ones(n, np.float32)
+               for k, n in zip(("m_samples", "r_samples"), lens)}
+    args, statics = _direct_args([64] * 4, [2] * 4, seed_period=2,
+                                 samples=samples)
+    jax.eval_shape(functools.partial(qn_kernel.qn_event_fwd, **statics,
+                                     interpret=True, seed_period=2), *args)
+    assert seen == [tables]
+
+
 @pytest.mark.parametrize("seeds,period", [
     ([0, 1000, 0, 1001], 2),       # a later lane breaks the period
     ([0, 1000, 0], 2),             # not a whole number of periods
@@ -211,6 +264,30 @@ def test_draw_columns_count_one_per_seed_with_the_period(replications):
     assert s2["dispatches"] - s1["dispatches"] == replications
     assert s2["draw_columns"] - s1["draw_columns"] \
         == s2["lanes"] - s1["lanes"] == replications
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas"])
+def test_replay_lookups_count_the_draws_the_kernel_copies(impl):
+    """A replay dispatch needs 2 draws per draw column and scan step; under
+    the pallas impl the lookup kernel copies every one of them."""
+    old = partition.shard_spec()
+    partition.set_shard_spec("off")
+    try:
+        s0 = qn_sim.sim_stats()
+        kw = {**BASE, **FAST, "h_users": 2, "slots": [2, 3, 5]}
+        qn_sim.response_time_batch(**kw, impl=impl, **REPLAY)
+        s1 = qn_sim.sim_stats()
+        qn_sim.response_time_batch(**kw, impl=impl)       # not replay
+        s2 = qn_sim.sim_stats()
+    finally:
+        partition.set_shard_spec(old)
+    d = {k: s1[k] - s0[k] for k in s1}
+    scan_len = d["events_total"] // d["lanes"]
+    assert d["replay_draws"] == 2 * d["draw_columns"] * scan_len > 0
+    assert d["replay_lookups"] == (d["replay_draws"] if impl == "pallas"
+                                   else 0)
+    assert s2["replay_draws"] == s1["replay_draws"]
+    assert s2["replay_lookups"] == s1["replay_lookups"]
 
 
 def test_single_slot_single_user_degenerate():
